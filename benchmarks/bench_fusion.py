@@ -28,7 +28,7 @@ CHAINS = {
 
 
 def run_chain(name, widths, n_points=8192,
-              budget=F.DEFAULT_ONCHIP_BUDGET_BYTES):
+              budget=F.VMEM_LIMIT_BYTES):
     groups = F.plan_fusion(widths, budget_bytes=budget)
     unfused = F.dram_bytes_unfused(n_points, widths)
     fused = F.dram_bytes_fused(n_points, widths, groups)

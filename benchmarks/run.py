@@ -33,6 +33,8 @@ def main(argv=None) -> None:
     args = ap.parse_args(argv)
     json_path = args.json or ("BENCH_models.json" if args.smoke else None)
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     header()
     from benchmarks import (bench_cache, bench_convflow, bench_fusion,
                             bench_mapping, bench_models, bench_moe,

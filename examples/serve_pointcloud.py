@@ -97,6 +97,7 @@ import time
 import numpy as np
 import jax
 
+from repro.compile_cache import enable_compile_cache
 from repro.data.synthetic import city_scene, lidar_scene
 from repro.models import minkunet as MU
 from repro.serve.buckets import geometric_ladder
@@ -542,6 +543,7 @@ def main():
                     help="write a Prometheus text-exposition snapshot "
                          "of the serve metrics registry (CI artifact)")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.partition and (args.workers or args.inject_faults):
         ap.error("--partition is its own smoke; it takes no --workers "
                  "or --inject-faults")
@@ -664,6 +666,14 @@ def main():
             sys.exit(1)
         print("fault-injection contract held: every request completed, "
               "transient failure retried, bad scenes rejected")
+    else:
+        failed = [r for r in results if not r.ok]
+        if failed or len(results) != len(scenes):
+            print(f"FAIL: {len(failed)} of {len(scenes)} requests not ok, "
+                  f"{len(results)} completed; first: "
+                  f"{failed[0].error if failed else 'missing result'}",
+                  file=sys.stderr)
+            sys.exit(1)
 
     if args.min_hit_rate is not None:
         lookups = mc["hits"] + mc["misses"] + ac["hits"] + ac["misses"]

@@ -27,8 +27,11 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import List, Sequence
 
-# TPU v5e VMEM is 128 MiB; leave headroom for weights + double buffering.
-DEFAULT_ONCHIP_BUDGET_BYTES = 64 * 1024 * 1024
+# Scoped VMEM limit the spconv kernels pass to the TPU compiler (whose own
+# default is 16 MiB on v5e), and the budget the conv planner fills.  A v5e
+# core has 128 MiB of VMEM; the rest is left to the compiler's internal
+# scratch.
+VMEM_LIMIT_BYTES = 96 * 1024 * 1024
 # candidate point-dim tile sizes (multiples of the 8-sublane MXU alignment)
 CANDIDATE_TILES = (4096, 2048, 1024, 512, 256, 128)
 
@@ -52,7 +55,7 @@ def _group_bytes(widths: Sequence[int], tile: int, dtype_bytes: int) -> int:
 
 
 def plan_fusion(layer_widths: Sequence[int],
-                budget_bytes: int = DEFAULT_ONCHIP_BUDGET_BYTES,
+                budget_bytes: int = VMEM_LIMIT_BYTES,
                 dtype_bytes: int = 4) -> List[FusionGroup]:
     """layer_widths: [in, h1, h2, ..., out] for a chain of len-1 FC layers.
 
@@ -104,23 +107,28 @@ class ConvFusionPlan:
 
 def plan_conv_epilogue(n_in: int, cin: int, cout: int, k: int, *,
                        residual: bool = False, out_tile: int = 128,
-                       budget_bytes: int = DEFAULT_ONCHIP_BUDGET_BYTES,
+                       budget_bytes: int = VMEM_LIMIT_BYTES,
                        dtype_bytes: int = 4) -> ConvFusionPlan:
     """Fusion plan for one sparse conv of K=`k` offsets, (cin -> cout)
     channels over an `n_in`-row input cloud.
 
-    Resident regardless of cache block: all K weight tiles, the f32
-    accumulator, the output tile, the inverse-table slice, and (if fused)
-    the epilogue operands — a residual skip tile and the per-channel
-    norm/bias vectors.  The feature cache block is double-buffered.
+    Counts what `spconv_fod_fused_pallas` allocates in VMEM, with the
+    channels untiled as `core.sparseconv` calls it.  Resident regardless
+    of cache block: all K weight tiles, the f32 accumulator, the
+    gathered-rows scratch, the output tile, and (if fused) the epilogue
+    operands — a residual skip tile and the per-channel norm/bias
+    vectors.  Every pipelined block (weights, output, epilogue operands,
+    feature cache block) is counted double-buffered.  The inverse-table
+    slice is an SMEM block and is not counted.  The default budget is the
+    scoped VMEM limit the kernels compile with (`VMEM_LIMIT_BYTES`).
     """
-    weights = k * cin * cout * dtype_bytes
+    weights = 2 * k * cin * cout * dtype_bytes
     acc = out_tile * cout * 4                     # f32 scratch
-    out_t = out_tile * cout * dtype_bytes
-    inv = k * out_tile * 4
-    epi = (out_tile * cout * dtype_bytes if residual else 0) \
-        + 3 * cout * dtype_bytes + out_tile * dtype_bytes
-    fixed = weights + acc + out_t + inv + epi
+    rows = out_tile * cin * dtype_bytes           # gathered-rows scratch
+    out_t = 2 * out_tile * cout * dtype_bytes
+    epi = 2 * ((out_tile * cout * dtype_bytes if residual else 0)
+               + 3 * cout * dtype_bytes + out_tile * dtype_bytes)
+    fixed = weights + acc + rows + out_t + epi
     # whole cloud resident first (one window, no sweeps), then shrinking
     # stream blocks — largest fitting block wins
     candidates = [_round_up(n_in, 8)] + [t for t in CONV_FEAT_TILES

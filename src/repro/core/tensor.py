@@ -194,7 +194,7 @@ class MapContext:
              residual: bool = False,
              budget_bytes: int | None = None) -> FU.ConvFusionPlan:
         """Memoized `core.fusion.plan_conv_epilogue` for one conv site."""
-        budget = budget_bytes or FU.DEFAULT_ONCHIP_BUDGET_BYTES
+        budget = budget_bytes or FU.VMEM_LIMIT_BYTES
         key = (n_in, cin, cout, k, residual, budget)
         if key not in self.plans:
             self.plans[key] = FU.plan_conv_epilogue(

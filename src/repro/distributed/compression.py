@@ -26,8 +26,6 @@ from __future__ import annotations
 from typing import Any, Tuple
 
 import jax
-
-from repro import compat
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
@@ -61,7 +59,7 @@ def compressed_psum(x: jnp.ndarray, axis_name: str,
     """Error-feedback int8 mean over `axis_name` (call inside shard_map).
 
     Returns (mean, new_error)."""
-    n = compat.axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     v = x.astype(jnp.float32) + error
     q, scale = _quantize_int8(v)
     new_error = v - _dequantize(q, scale, x.shape, jnp.float32)
@@ -128,7 +126,7 @@ def hierarchical_grads(grad_fn, mesh, params, batch, errors):
         batch)
     params_stacked = jax.tree_util.tree_map(
         lambda x: jnp.broadcast_to(x[None], (n_pods,) + x.shape), params)
-    grads, new_err, metrics = compat.shard_map(
+    grads, new_err, metrics = jax.shard_map(
         local, mesh=mesh, axis_names={"pod"},
         in_specs=(pod, batch_spec, err_spec),
         out_specs=(pod, err_spec, P("pod")),

@@ -22,8 +22,6 @@ from __future__ import annotations
 from typing import Callable
 
 import jax
-
-from repro import compat
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
@@ -41,7 +39,7 @@ def pipeline_apply(body_fn: Callable, stage_params, x, n_micro: int,
     Returns (n_micro, micro_batch, seq, d) output from the LAST stage
     (other stages return zeros — caller selects).
     """
-    n_stages = compat.axis_size(axis_name)
+    n_stages = lax.axis_size(axis_name)
     stage = lax.axis_index(axis_name)
     n_ticks = n_micro + n_stages - 1
     mb_shape = x.shape[1:]
@@ -74,9 +72,9 @@ def pipeline_apply(body_fn: Callable, stage_params, x, n_micro: int,
 
     # carries vary across pipeline stages: mark them pod-varying for the
     # vma (varying-manual-axes) type system
-    inbuf0 = compat.pcast_varying(jnp.zeros(mb_shape, x.dtype),
-                                  (axis_name,))
-    outputs0 = compat.pcast_varying(jnp.zeros_like(x), (axis_name,))
+    inbuf0 = lax.pcast(jnp.zeros(mb_shape, x.dtype), (axis_name,),
+                       to="varying")
+    outputs0 = lax.pcast(jnp.zeros_like(x), (axis_name,), to="varying")
     (_, outputs), _ = lax.scan(tick, (inbuf0, outputs0),
                                jnp.arange(n_ticks))
     # broadcast the last stage's outputs to every stage (masked psum: only
@@ -117,7 +115,7 @@ def pipelined_forward(body_fn, params_layers, x, mesh, n_micro: int = 4):
         return out.reshape(xb.shape)
 
     stage_spec = jax.tree_util.tree_map(lambda _: P("pod"), staged)
-    return compat.shard_map(
+    return jax.shard_map(
         local, mesh=mesh, axis_names={"pod"},
         in_specs=(stage_spec, P()),
         out_specs=P(),

@@ -306,17 +306,15 @@ def shard_over_scenes(fn, mesh: Mesh, axis: str = "scene"):
     shard_map body is built once per arity, not per call — the pipelined
     scheduler dispatches from the submit hot path.
     """
-    from repro import compat
-
     spec = P(axis)
     bodies: dict[int, object] = {}
 
     def sharded(*args):
         body = bodies.get(len(args))
         if body is None:
-            body = bodies[len(args)] = compat.shard_map(
+            body = bodies[len(args)] = jax.shard_map(
                 fn, mesh=mesh, in_specs=tuple(spec for _ in args),
-                out_specs=spec, axis_names={axis})
+                out_specs=spec, axis_names={axis}, check_vma=False)
         return body(*args)
 
     return sharded

@@ -26,7 +26,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro import compat
 
 _NEG_INF = -1e30
 _LANES = 128
@@ -117,7 +116,7 @@ def flash_decode_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                           block_s=block_s, n_s=n_s),
         grid_spec=gs,
         out_shape=jax.ShapeDtypeStruct((b * hkv, g, hd), q.dtype),
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="flash_decode",

@@ -22,8 +22,8 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+import jax.experimental.pallas.tpu as pltpu
 
-from repro import compat
 
 
 def _kernel(x_ref, *refs, n_layers: int, final_act: bool):
@@ -65,7 +65,7 @@ def fused_mlp_pallas(x: jnp.ndarray, weights: Sequence[jnp.ndarray],
         in_specs=in_specs,
         out_specs=pl.BlockSpec((tile_points, c_out), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, c_out), x.dtype),
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
         name=f"fused_mlp_x{n_layers}",

@@ -27,7 +27,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro import compat
 
 
 def _kernel(eid_ref, x_ref, w_ref, o_ref, acc_ref, *, n_ci):
@@ -79,7 +78,7 @@ def grouped_matmul_pallas(x: jnp.ndarray, tile_eid: jnp.ndarray,
         functools.partial(_kernel, n_ci=n_ci),
         grid_spec=gs,
         out_shape=jax.ShapeDtypeStruct((r, cout), x.dtype),
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
         name="grouped_matmul_fod",

@@ -3,7 +3,8 @@
 Two entry points mirror the two kernels in spconv.py:
 
   * `sparse_conv_fod`   — baseline whole-array-resident kernel
-    (`flow="pallas"`).
+    (`flow="pallas"`); channels past `BASE_CIN_TILE` are tiled so the
+    resident (N, cin_tile) column fits VMEM at bucket-size clouds.
   * `sparse_conv_fused` — streamed feature tiles + fused epilogue
     (`flow="pallas_fused"`): derives the scalar-prefetched window schedule
     from the inverse table, pads rows/channels to the tile grid, and folds
@@ -45,6 +46,11 @@ def invert_maps(maps: KernelMaps, out_cap: int) -> jnp.ndarray:
                             (k, cap))
     return inv.at[rows.reshape(-1), oidx.reshape(-1)].set(
         maps.in_idx.reshape(-1), mode="drop")
+
+
+# contraction tile of the baseline kernel: one lane width, so a 65,536-row
+# resident feature column is 32 MiB of f32
+BASE_CIN_TILE = 128
 
 
 def _round_up(x: int, m: int) -> int:
@@ -103,8 +109,12 @@ def _sparse_conv_fod(features: jnp.ndarray, maps: KernelMaps,
     inv = invert_maps(maps, out_cap)
     m_pad = _round_up(out_cap, out_tile)
     inv = jnp.pad(inv, ((0, 0), (0, m_pad - out_cap)), constant_values=-1)
+    cin_tile = None
+    if features.shape[1] > BASE_CIN_TILE:
+        cin_tile = BASE_CIN_TILE
+        features, weights = _pad_cin(features, weights, cin_tile)
     out = spconv_fod_pallas(features, inv, weights, out_tile=out_tile,
-                            interpret=interpret)
+                            cin_tile=cin_tile, interpret=interpret)
     return out[:out_cap]
 
 
@@ -116,6 +126,7 @@ def sparse_conv_fod(features: jnp.ndarray, maps: KernelMaps,
 
     interpret=None auto-selects from the active backend: compiled on TPU,
     interpreter everywhere else (CPU validation).  Pass a bool to override.
+    On a TPU the kernel is always compiled: a compile error raises.
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"

@@ -16,7 +16,10 @@ TPU adaptation of the paper's dataflow:
   * fetch-on-demand: input rows are gathered inside the kernel from the
     VMEM-resident feature block immediately before the matmul — the gathered
     matrix is never materialised in HBM (the paper's 3x DRAM saving,
-    Fig. 11c).
+    Fig. 11c).  The gather is one dynamic single-row slice per output row
+    and offset, addressed by the tile's slice of the inverse table held in
+    SMEM (`_gather_rows`): Mosaic lowers no vector gather across rows, and
+    a row copy costs the same whatever the size of the resident block.
 
 Two kernels:
 
@@ -51,15 +54,49 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro import compat
+from repro.core.fusion import VMEM_LIMIT_BYTES
 
 LN_EPS = 1e-6  # must match repro.nn.layernorm
+# rows gathered per loop iteration (Mosaic unrolls fori_loop only fully)
+ROW_UNROLL = 8
 
 
-def _kernel(inv_ref, feat_ref, w_ref, out_ref, acc_ref, *, n_k, n_cin):
+def _gather_rows(inv_ref, k, feat_ref, rows_ref, base=0):
+    """rows_ref[j] <- feat_ref[inv_ref[k, j] - base] for each row j of the
+    output tile whose source row lies in the resident feature block, and
+    zero for the rest (no map, or a row of another window)."""
+    n_rows = feat_ref.shape[0]
+
+    def body(g, carry):
+        for u in range(ROW_UNROLL):
+            j = g * ROW_UNROLL + u
+            src = inv_ref[k, j]
+            loc = src - base
+            ok = (src >= 0) & (loc >= 0) & (loc < n_rows)
+            row = feat_ref[pl.ds(jnp.clip(loc, 0, n_rows - 1), 1), :]
+            rows_ref[pl.ds(j, 1), :] = jnp.where(ok, row, 0).astype(
+                rows_ref.dtype)
+        return carry
+
+    lax.fori_loop(0, rows_ref.shape[0] // ROW_UNROLL, body, 0)
+
+
+def _check_out_tile(out_tile: int, m: int, where: str):
+    if m % out_tile != 0:
+        raise ValueError(
+            f"output rows m={m} not a multiple of out_tile={out_tile}; pad "
+            f"the inverse table ({where} does)")
+    if out_tile % ROW_UNROLL != 0:
+        raise ValueError(
+            f"out_tile={out_tile} must be a multiple of {ROW_UNROLL}")
+
+
+def _kernel(inv_ref, feat_ref, w_ref, out_ref, acc_ref, rows_ref, *, n_k,
+            n_cin):
     ci = pl.program_id(1)
     k = pl.program_id(2)
 
@@ -67,11 +104,8 @@ def _kernel(inv_ref, feat_ref, w_ref, out_ref, acc_ref, *, n_k, n_cin):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    idx = inv_ref[0, :]                                   # (T,) int32
-    valid = idx >= 0
-    rows = jnp.take(feat_ref[...], jnp.maximum(idx, 0), axis=0)
-    rows = jnp.where(valid[:, None], rows, 0.0)
-    acc_ref[...] += jnp.dot(rows, w_ref[0],
+    _gather_rows(inv_ref, k, feat_ref, rows_ref)
+    acc_ref[...] += jnp.dot(rows_ref[...], w_ref[0],
                             preferred_element_type=jnp.float32)
 
     @pl.when((k == n_k - 1) & (ci == n_cin - 1))
@@ -97,10 +131,7 @@ def spconv_fod_pallas(features: jnp.ndarray, inv_idx: jnp.ndarray,
         raise ValueError(
             f"cin={cin} is not a multiple of cin_tile={cin_tile}; pad the "
             "channel dim (ops.sparse_conv_fod does) or pick a divisor")
-    if m % out_tile != 0:
-        raise ValueError(
-            f"output rows m={m} not a multiple of out_tile={out_tile}; pad "
-            "the inverse table (ops.sparse_conv_fod does)")
+    _check_out_tile(out_tile, m, "ops.sparse_conv_fod")
     n_cin = cin // cin_tile
 
     grid = (m // out_tile, n_cin, k)
@@ -109,16 +140,19 @@ def spconv_fod_pallas(features: jnp.ndarray, inv_idx: jnp.ndarray,
         functools.partial(_kernel, n_k=k, n_cin=n_cin),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, out_tile), lambda o, ci, kk: (kk, o)),
+            pl.BlockSpec((k, out_tile), lambda o, ci, kk: (0, o),
+                         memory_space=pltpu.SMEM),
             pl.BlockSpec((n, cin_tile), lambda o, ci, kk: (0, ci)),
             pl.BlockSpec((1, cin_tile, cout),
                          lambda o, ci, kk: (kk, ci, 0)),
         ],
         out_specs=pl.BlockSpec((out_tile, cout), lambda o, ci, kk: (o, 0)),
         out_shape=jax.ShapeDtypeStruct((m, cout), features.dtype),
-        scratch_shapes=[pltpu.VMEM((out_tile, cout), jnp.float32)],
-        compiler_params=compat.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+        scratch_shapes=[pltpu.VMEM((out_tile, cout), jnp.float32),
+                        pltpu.VMEM((out_tile, cin_tile), features.dtype)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
         name="spconv_fetch_on_demand",
     )(inv_idx, features, weights)
@@ -139,6 +173,7 @@ def _fused_kernel(wmap_ref, nwin_ref, inv_ref, feat_ref, w_ref, *rest,
     mask_ref = next(it) if has_mask else None
     out_ref = next(it)
     acc_ref = next(it)
+    rows_ref = next(it)
 
     o = pl.program_id(0)
     ci = pl.program_id(1)
@@ -153,20 +188,15 @@ def _fused_kernel(wmap_ref, nwin_ref, inv_ref, feat_ref, w_ref, *rest,
     # and are skipped so no row is counted twice.
     @pl.when(wi < nwin_ref[o])
     def _compute():
-        base = wmap_ref[o, wi] * feat_tile
-        feat = feat_ref[...]                              # (F, cin_tile)
-        for k in range(n_k):                              # static unroll
-            idx = inv_ref[k, :]                           # (T,) int32
-            loc = idx - base
-            ok = (idx >= 0) & (loc >= 0) & (loc < feat_tile)
+        base = wmap_ref[o * n_win + wi] * feat_tile
 
-            @pl.when(jnp.any(ok))
-            def _dot():
-                rows = jnp.take(feat, jnp.clip(loc, 0, feat_tile - 1),
-                                axis=0)
-                rows = jnp.where(ok[:, None], rows, 0.0)
-                acc_ref[...] += jnp.dot(rows, w_ref[k],
-                                        preferred_element_type=jnp.float32)
+        def offset(k, carry):
+            _gather_rows(inv_ref, k, feat_ref, rows_ref, base)
+            acc_ref[...] += jnp.dot(rows_ref[...], w_ref[k],
+                                    preferred_element_type=jnp.float32)
+            return carry
+
+        lax.fori_loop(0, n_k, offset, 0)
 
     @pl.when((ci == n_cin - 1) & (wi == n_win - 1))
     def _flush():
@@ -203,9 +233,10 @@ def spconv_fod_fused_pallas(features: jnp.ndarray, inv_idx: jnp.ndarray,
     weights (K, Cin, Cout) -> (M, Cout).
 
     wmap (M/out_tile, N/feat_tile) int32 and nwin (M/out_tile,) int32 are
-    the scalar-prefetched window schedule: out tile o visits feature row
-    blocks wmap[o, 0..nwin[o]-1] (ops.py derives them from the inverse
-    table).  Epilogue (all optional, applied in this order at flush):
+    the window schedule: out tile o visits feature row blocks
+    wmap[o, 0..nwin[o]-1] (ops.py derives them from the inverse table).
+    Both are scalar-prefetched, wmap flattened to 1-D (SMEM pads the
+    minor dim of a 2-D array).  Epilogue (all optional, applied in this order at flush):
     +bias (1, Cout) -> layernorm (ln_scale/ln_bias (1, Cout)) ->
     +residual (M, Cout) -> ReLU -> *mask (M, 1).
     """
@@ -217,10 +248,7 @@ def spconv_fod_fused_pallas(features: jnp.ndarray, inv_idx: jnp.ndarray,
         raise ValueError(
             f"cin={cin} is not a multiple of cin_tile={cin_tile}; pad the "
             "channel dim (ops.sparse_conv_fused does) or pick a divisor")
-    if m % out_tile != 0:
-        raise ValueError(
-            f"output rows m={m} not a multiple of out_tile={out_tile}; pad "
-            "the inverse table (ops.sparse_conv_fused does)")
+    _check_out_tile(out_tile, m, "ops.sparse_conv_fused")
     if n % feat_tile != 0:
         raise ValueError(
             f"feature rows n={n} not a multiple of feat_tile={feat_tile}; "
@@ -241,9 +269,10 @@ def spconv_fod_fused_pallas(features: jnp.ndarray, inv_idx: jnp.ndarray,
     has_mask = mask is not None
 
     in_specs = [
-        pl.BlockSpec((k, out_tile), lambda o, ci, wi, wm, nw: (0, o)),
+        pl.BlockSpec((k, out_tile), lambda o, ci, wi, wm, nw: (0, o),
+                     memory_space=pltpu.SMEM),
         pl.BlockSpec((feat_tile, cin_tile),
-                     lambda o, ci, wi, wm, nw: (wm[o, wi], ci)),
+                     lambda o, ci, wi, wm, nw: (wm[o * n_win + wi], ci)),
         pl.BlockSpec((k, cin_tile, cout),
                      lambda o, ci, wi, wm, nw: (0, ci, 0)),
     ]
@@ -272,7 +301,8 @@ def spconv_fod_fused_pallas(features: jnp.ndarray, inv_idx: jnp.ndarray,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((out_tile, cout),
                                lambda o, ci, wi, wm, nw: (o, 0)),
-        scratch_shapes=[pltpu.VMEM((out_tile, cout), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((out_tile, cout), jnp.float32),
+                        pltpu.VMEM((out_tile, cin_tile), features.dtype)],
     )
     return pl.pallas_call(
         functools.partial(_fused_kernel, n_k=k, n_cin=n_cin, n_win=n_win,
@@ -281,8 +311,9 @@ def spconv_fod_fused_pallas(features: jnp.ndarray, inv_idx: jnp.ndarray,
                           relu=relu),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, cout), features.dtype),
-        compiler_params=compat.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
         name="spconv_fod_fused",
-    )(wmap, nwin, *operands)
+    )(wmap.reshape(-1), nwin, *operands)

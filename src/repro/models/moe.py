@@ -166,7 +166,7 @@ def moe_apply_ep(p: nn.Params, cfg: ArchConfig, x: jnp.ndarray, *,
     the Megatron-SP boundary layout directly (no entry all-gather).
     """
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
+    from jax import shard_map
 
     ep = mesh.shape[model_axis]
     e = cfg.n_experts

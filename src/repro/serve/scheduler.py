@@ -158,7 +158,12 @@ class _HostArena:
     allocation), rotating over `depth` slots so assembling batch i+1
     never touches the slot batch i was shipped from — the host half of
     the double buffer.  feats is allocated lazily on first fill (channel
-    count and dtype come from traffic, not config).
+    count and dtype come from traffic, not config).  Slots are refilled
+    in place, so `stage` copies a slot to the device and waits for the
+    copy: on the CPU backend `jnp.asarray` (and `device_put`, whatever
+    `may_alias` says) aliases the slot's memory, and an unfinished copy
+    still reads it, so the next fill would rewrite an in-flight or cached
+    batch.
     """
 
     def __init__(self, depth: int, max_batch: int, cap: int,
@@ -180,6 +185,10 @@ class _HostArena:
             self.feats = np.zeros(shape, feats_like.dtype)
         self._slot = (self._slot + 1) % self.depth
         return self._slot
+
+    @staticmethod
+    def stage(slot: np.ndarray) -> jax.Array:
+        return jax.block_until_ready(jnp.array(slot, copy=True))
 
 
 def _jit_cache_size(fn) -> int:
@@ -822,7 +831,7 @@ class ServeScheduler:
             arena.feats[s, i] = r.feats
         if n_dummy:                     # clear stale rows from fuller runs
             arena.feats[s, n_real:] = 0
-        feats_b = jnp.asarray(arena.feats[s])
+        feats_b = arena.stage(arena.feats[s])
 
         comp_key = (cap, mb, n_dummy, tuple(r.key for r in reqs))
         if marks is not None:
@@ -841,8 +850,8 @@ class ServeScheduler:
             if n_dummy:
                 arena.coords[s, n_real:] = M.SENTINEL
                 arena.mask[s, n_real:] = False
-            coords_b = jnp.asarray(arena.coords[s])
-            mask_b = jnp.asarray(arena.mask[s])
+            coords_b = arena.stage(arena.coords[s])
+            mask_b = arena.stage(arena.mask[s])
             per = [self.engine._levels_padded(r.coords, r.mask, cap,
                                               key=r.key) for r in reqs]
             hits = [h for _, h in per]

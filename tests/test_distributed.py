@@ -13,9 +13,9 @@ import textwrap
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def run_sub(code: str, timeout=600):
+def run_sub(code: str, timeout=600, n_devices=8):
     env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     r = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
                        capture_output=True, text=True, timeout=timeout,
@@ -137,7 +137,6 @@ def test_compressed_psum_error_feedback():
     run_sub("""
         import jax, numpy as np, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from repro import compat
         from repro.distributed import compression as C
         from repro.launch.mesh import make_debug_mesh
 
@@ -148,9 +147,9 @@ def test_compressed_psum_error_feedback():
             def local(xl, e):
                 m, e2 = C.compressed_psum(xl[0], "pod", e[0])
                 return m[None], e2[None]
-            return compat.shard_map(local, mesh=mesh, axis_names={"pod"},
-                                    in_specs=(P("pod"), P("pod")),
-                                    out_specs=(P("pod"), P("pod")))(x, err)
+            return jax.shard_map(local, mesh=mesh, axis_names={"pod"},
+                                 in_specs=(P("pod"), P("pod")),
+                                 out_specs=(P("pod"), P("pod")))(x, err)
 
         shape = (2, 1, 300)                  # (pod, local_rows, dim)
         err = jnp.zeros(shape, jnp.float32)
@@ -293,6 +292,32 @@ def test_sharded_serve_scheduler_matches_per_scene_loop():
         assert len(stats["buckets"]) == 2
         print("OK")
     """)
+
+
+def test_chip_smoke_four_chip_path_matches_one_device():
+    """chip_smoke.py --four-chips at tiny size on 4 virtual devices: the
+    scheduler shards micro-batches over a 4-device scene mesh and every
+    scene agrees with the one-device per-scene reference."""
+    out = run_sub(f"""
+        import sys, jax
+        sys.path.insert(0, {REPO!r})
+        import chip_smoke as CS
+        from repro.distributed import sharding as SH
+
+        devs = jax.devices()
+        assert len(devs) == 4
+        scenes = CS.make_scenes(6, sizes=(90, 40), seed=2)
+        params = CS.init_params(0, stem=8, enc_planes=(8, 16),
+                                dec_planes=(16, 8), blocks_per_stage=1)
+        mesh = SH.make_scene_mesh(devices=devs)
+        preds, _, stats = CS.serve(params, scenes, max_batch=4, mesh=mesh)
+        assert stats["n_devices"] == 4 and stats["n_completed"] == 6
+        refs = CS.reference_logits(params, scenes, devs[0])
+        reports = CS.check_served(preds, refs, scenes)
+        assert all(r["ok"] for r in reports)
+        print("OK", min(r["agree"] for r in reports))
+    """, n_devices=4)
+    assert "OK" in out
 
 
 def test_checkpoint_roundtrip_and_elastic(tmp_path):

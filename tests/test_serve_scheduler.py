@@ -31,7 +31,7 @@ from repro.models import minkunet as MU
 from repro.serve.buckets import (BucketLadder, geometric_ladder,
                                  max_batch_from_occupancy, pad_scene)
 from repro.serve.engine import PointCloudEngine
-from repro.serve.scheduler import ServeScheduler
+from repro.serve.scheduler import ServeScheduler, _HostArena
 
 
 def _mini_params(n_classes=2):
@@ -48,6 +48,20 @@ def _ref_preds(params, coords, mask, feats, flow="fod"):
 # ---------------------------------------------------------------------------
 # bucket ladder policy
 # ---------------------------------------------------------------------------
+
+def test_arena_stage_survives_refill():
+    """A staged slot is a copy: refilling the one slot in place (as the
+    next micro-batch does) leaves the staged device arrays unchanged."""
+    arena = _HostArena(1, 2, 64, 4)
+    s = arena.next_slot(np.zeros((64, 4), np.float32))
+    arena.feats[s] = 1.0
+    arena.mask[s] = True
+    feats, mask = arena.stage(arena.feats[s]), arena.stage(arena.mask[s])
+    assert arena.next_slot(np.zeros((64, 4), np.float32)) == s
+    arena.feats[s] = 2.0
+    arena.mask[s] = False
+    assert (np.asarray(feats) == 1.0).all() and np.asarray(mask).all()
+
 
 def test_bucket_ladder_selection_and_bounds():
     ladder = BucketLadder((64, 128, 256))
