@@ -66,25 +66,9 @@ The multi-worker router PR adds two more rows:
                          (the failover + replay pipeline cost, no
                          compile in the path)
 
-The observability PR adds one more row (and upgrades the latency rows:
-serve/sync_per_scene and serve/pipe_per_scene now carry p50/p95/p99
-from the registry's per-request latency histogram into BENCH_*.json):
-
-  serve/obs_overhead     steady-state cost of the FULL observability
-                         stack (span tracer + flight recorder on top of
-                         the always-on metrics registry) in % of
-                         per-scene latency.  Like ft_overhead /
-                         router_overhead, the asserted number is the
-                         per-request obs work timed directly — one
-                         trace begin/end, the ~8 spans a served request
-                         records, the recorder ring appends, and the
-                         histogram/counter updates — against the
-                         measured per-scene latency; the end-to-end A/B
-                         delta is reported informationally.  Parity is
-                         asserted first: an obs-enabled scheduler must
-                         produce bit-identical predictions to the
-                         default (metrics-only) one.  Acceptance:
-                         <= 3%, asserted in the full run.
+The observability PR upgrades the latency rows: serve/sync_per_scene
+and serve/pipe_per_scene carry p50/p95/p99 from the registry's
+per-request latency histogram into BENCH_*.json.
 
 The overload-control PR adds two rows:
 
@@ -118,7 +102,6 @@ before any row is emitted.
 from __future__ import annotations
 
 import argparse
-import gc
 import time
 
 import numpy as np
@@ -486,117 +469,6 @@ def bench_router(n_points: int, reps: int, windows: int,
     return overhead
 
 
-def bench_obs(n_points: int, reps: int, windows: int,
-              max_batch: int = 4, assert_overhead: bool = True):
-    """serve/obs_overhead: the full observability stack (span tracer +
-    flight recorder) vs the default metrics-only scheduler on the
-    repeated-composition stream.  Parity (bit-identical predictions)
-    asserted first; the asserted overhead is the per-request obs work
-    timed directly against the measured per-scene latency (the same
-    direct-measurement discipline as ft_overhead/router_overhead — an
-    end-to-end A/B delta of a sub-1% effect is drift noise)."""
-    from repro.obs import Observability
-
-    params = MU.minkunet_init(jax.random.key(0), c_in=4, n_classes=4,
-                              stem=8, enc_planes=(8, 16),
-                              dec_planes=(16, 8), blocks_per_stage=1)
-    scenes = [lidar_scene(seed=21 + i, n_points=n_points, grid=32)
-              for i in range(max_batch)]
-
-    def build(obs=None):
-        engine = PointCloudEngine(params, n_stages=2, flow="fod",
-                                  ladder=BucketLadder((n_points,)),
-                                  max_batch=max_batch, mesh=None)
-        return ServeScheduler(engine, max_batch=max_batch, mesh=None,
-                              obs=obs)
-
-    base = build()                           # always-on metrics only
-    full = build(obs=Observability.enabled())
-
-    # parity + warmup: tracing must never perturb predictions
-    ref = _stream_once(base, scenes)
-    got = _stream_once(full, scenes)
-    for rid in ref:
-        np.testing.assert_array_equal(ref[rid].preds, got[rid].preds)
-
-    base_w, full_w = [], []
-    for _ in range(windows):
-        base_w.append(_window_us(base, scenes, reps))
-        full_w.append(_window_us(full, scenes, reps))
-    base_us = float(np.median(base_w))
-    full_us = float(np.median(full_w))
-    e2e_delta = full_us / base_us - 1.0
-
-    # the tracer+recorder's per-request addition, timed directly: one
-    # root begin/end, the span set a served request records (admission,
-    # queue_wait, dispatch, assembly + its two children, device_wait,
-    # retire event), the recorder ring appends, and the registry updates
-    # the request also pays on the metrics-only path
-    obs = Observability.enabled()
-    tr, rec = obs.tracer, obs.recorder
-    h = obs.registry.histogram("bench_latency_seconds", "bench")
-    c = obs.registry.counter("bench_requests_total", "bench")
-    n_req = 1000
-    # GC hygiene: the loop's small allocations otherwise trigger cyclic
-    # collections that scan the whole bench-process heap (jax traces,
-    # caches) and get billed to the obs work — the serving hot path
-    # amortizes those same collections over full scene executions
-    gc.collect()
-    gc_was_enabled = gc.isenabled()
-    gc.disable()
-    t0 = time.perf_counter()
-    for i in range(n_req):
-        tid = f"bench:rid:{i}"
-        tr.begin(tid, t=0.0, rid=i, instance="bench")
-        tr.span(tid, "admission", t_start=0.0, t_end=0.0,
-                bucket=n_points, n_points=n_points)
-        q = tr.span(tid, "queue_wait", t_start=0.0)
-        tr.end_span(tid, q, t_end=0.0)
-        tr.span(tid, "dispatch", t_start=0.0, t_end=0.0,
-                dispatch_id=i, bucket=n_points, retries=0)
-        a = tr.span(tid, "assembly", t_start=0.0, t_end=0.0,
-                    cache_hit=True)
-        tr.span(tid, "arena_staging", parent=a, t_start=0.0, t_end=0.0)
-        tr.span(tid, "assembly_lookup", parent=a, t_start=0.0,
-                t_end=0.0)
-        w = tr.span(tid, "device_wait", t_start=0.0)
-        tr.end_span(tid, w, t_end=0.0)
-        tr.event(tid, "retire", t=0.0, latency_s=0.001)
-        tr.end(tid, t=0.0, outcome="ok")
-        rec.record("submit", t=0.0, rid=i, bucket=n_points)
-        rec.record("dispatch", t=0.0, rids=(i,))
-        rec.record("retire", t=0.0, rids=(i,))
-        h.observe(0.001)
-        h.observe(0.001)
-        h.observe(0.001)
-        c.inc()
-        c.inc()
-        c.inc()
-    obs_us = (time.perf_counter() - t0) * 1e6 / n_req
-    if gc_was_enabled:
-        gc.enable()
-    overhead = obs_us / base_us
-    st = full.stats()
-    q = st["latency_quantiles_s"]
-    emit("serve/obs_overhead", overhead * 100,
-         f"obs_us={obs_us:.1f};per_scene_us={base_us:.0f};"
-         f"e2e_delta_pct={e2e_delta * 100:.1f};parity=ok;"
-         f"spans_per_req=9;target_pct=3",
-         extra={"latency_quantiles_us":
-                {k: v * 1e6 for k, v in q.items()},
-                "tracer": full.obs.tracer.stats(),
-                "recorder": full.obs.recorder.stats()})
-    base.close()
-    full.close()
-
-    if assert_overhead:
-        assert overhead <= 0.03, (
-            f"the enabled tracer+recorder must cost <= 3% per scene on "
-            f"the steady state, got {overhead * 100:.1f}% "
-            f"({obs_us:.1f}us of obs work vs {base_us:.0f}us/scene)")
-    return overhead
-
-
 def bench_overload(n_points: int, reps: int, windows: int,
                    max_batch: int = 4, n_scenes: int = 120,
                    storm_rate: float = 10.0,
@@ -642,7 +514,7 @@ def bench_overload(n_points: int, reps: int, windows: int,
     # check) plus the dispatch-success breaker hook — time it directly
     # against the per-scene latency; the interleaved windows provide
     # the denominator and an informational e2e delta (a sub-1% effect
-    # drowns in host drift, same story as ft/router/obs overhead)
+    # drowns in host drift, same story as ft/router overhead)
     plain_w, ctrl_w = [], []
     for _ in range(windows):
         plain_w.append(_window_us(plain, scenes, reps))
@@ -744,8 +616,6 @@ def main(argv=None):
                               assert_overhead=False)
         bench_router(n_points=128, reps=3, windows=3,
                      assert_overhead=False)
-        bench_obs(n_points=128, reps=3, windows=3,
-                  assert_overhead=False)
         bench_overload(n_points=128, reps=3, windows=3, n_scenes=90,
                        storm_rate=15.0, assert_goodput=False)
         bench_partition(n_points=3000, budgets=(512, 1024), reps=1)
@@ -753,7 +623,6 @@ def main(argv=None):
         bench_hot_loop(n_points=128, reps=6, windows=5)
         bench_fault_tolerance(n_points=128, reps=6, windows=5)
         bench_router(n_points=128, reps=8, windows=5)
-        bench_obs(n_points=128, reps=6, windows=5)
         bench_overload(n_points=128, reps=6, windows=5, n_scenes=120,
                        storm_rate=10.0)
         bench_partition(n_points=12000, budgets=(1024, 2048, 4096))

@@ -1,32 +1,45 @@
 """Request span tracing for the serving stack.
 
-One serve request crosses many stages — admission, bucket queue,
-assembly, device dispatch, retire, and (multi-worker / city-scale) a
-router hop, failover replay, or partition fan-out — and the aggregate
-metrics can say *that* p99 moved without saying *where*.  `SpanTracer`
-answers "where did this request's 40 ms go": every request id owns a
-**span tree** under one trace, each span carrying monotonic start/end
-timestamps and small attribute dicts, so a single trace reconstructs the
-request's whole path:
+One serve request crosses many stages — admission, the scheduler lock,
+bucket queue, assembly, device dispatch, retire, hand-out, and
+(multi-worker / city-scale) a router hop, failover replay, or partition
+fan-out — and the aggregate metrics can say *that* p99 moved without
+saying *where*.  `SpanTracer` answers "where did this request's 40 ms
+go": every request id owns a **span tree** under one trace, each span
+carrying monotonic start/end timestamps and small attribute dicts, so a
+single trace reconstructs the request's whole path.  The root's direct
+children tile it from `submit` to the hand-out of the result:
 
     request (root)                          rid=3 instance=w1
-    ├─ route          0.00ms → 0.04ms       worker=w1      (router only)
-    ├─ admission      0.04ms → 0.21ms       bucket=512
-    ├─ queue_wait     0.21ms → 3.90ms       bucket=512
-    ├─ dispatch       3.90ms → 5.10ms       dispatch_id=7 retries=0
-    │  └─ assembly    3.90ms → 4.60ms       cache_hit=True
-    │     ├─ arena_staging     3.90 → 4.1
-    │     └─ assembly_lookup   4.1  → 4.2
-    ├─ device_wait    5.10ms → 38.7ms
-    └─ retire         38.7ms                (instant)
+    ├─ route          0.00 → 0.04 ms        worker=w1      (router only)
+    ├─ admission      0.04 → 0.21 ms        bucket=512  (before the lock)
+    ├─ lock_wait      0.21 → 0.25 ms        waiting for the scheduler lock
+    ├─ queue_wait     0.25 → 3.90 ms        bucket=512
+    ├─ dispatch       3.90 → 5.10 ms        dispatch_id=7 retries=0 n_real=2
+    │  │                                    trigger=full thread=MainThread
+    │  ├─ assembly    3.90 → 4.90 ms        cache_hit=False
+    │  │  ├─ arena_staging     3.90 → 4.00  (feats)
+    │  │  ├─ assembly_lookup   4.00 → 4.01  (the AssemblyCache lookup)
+    │  │  ├─ arena_staging     4.01 → 4.05  (coords, mask; on a miss)
+    │  │  ├─ mapping           4.05 → 4.60  rows bucket cache_hit
+    │  │  └─ stack_levels      4.60 → 4.90  dispatch_id=7
+    │  └─ launch      4.90 → 5.10 ms        host call of the jitted apply
+    ├─ device_wait    5.10 → 38.7 ms
+    ├─ retire         38.7 ms               (instant)
+    └─ result_wait    38.7 → 39.0 ms        until poll/drain/take hands it out
 
-Failure paths appear as spans too: `dispatch_failed`, `failover`
-(attrs: dead worker + reason), `replay` (attrs: surviving worker) — so a
-chaos-run trace shows original dispatch → failover → replay → retire in
-one tree.
+`trigger` says why the micro-batch ran: `full` (its bucket filled in
+`submit`), `deadline` (`max_wait_s`), `flush` (flush/close), `pump`
+(a deferred batch under an overload controller) or `retry`.  `mapping`
+is recorded in the tree of the request whose scene it mapped,
+`stack_levels` in every request of the micro-batch.
 
-Design constraints (the ≤3% overhead gate in `bench_serve
-serve/obs_overhead` is asserted against this implementation):
+Failure paths appear as spans too: `dispatch_failed`, `error`,
+`failover` (attrs: dead worker + reason), `replay` (attrs: surviving
+worker) — so a chaos-run trace shows original dispatch → failover →
+replay → retire in one tree.
+
+Design constraints:
 
   * recording a span is one dict + one list append under a leaf lock —
     no I/O, no string formatting on the hot path;
@@ -35,6 +48,11 @@ serve/obs_overhead` is asserted against this implementation):
   * finished traces park in a bounded deque (`max_finished`) — a
     long-running server never grows without bound; exporters drain or
     snapshot them (`repro.obs.export.write_trace_jsonl`).
+
+`anchor()` ties the tracer's monotonic clock to a JAX profiler trace:
+it stamps the clock around a `TraceAnnotation` whose host event the
+trace records, so a reader maps spans onto the trace's clock by the
+offset between the two (see `bench/spans.py`).
 
 Trace ids are plain strings.  The component that BEGINS a trace owns
 its root (and ends it); components handed a `trace_id` (a router's
@@ -128,6 +146,7 @@ class SpanTracer:
         self._finished: deque[Trace] = deque(maxlen=max_finished)
         self._next_span = 0
         self.n_dropped = 0          # spans for unknown/finished traces
+        self.anchors: list[tuple[str, int, int]] = []
 
     # -- recording --------------------------------------------------------
 
@@ -206,6 +225,22 @@ class SpanTracer:
                     root.t_end = t
                 root.attrs.update(attrs)
             self._finished.append(tr)
+
+    def anchor(self, name: str = "obs.clock") -> tuple[int, int]:
+        """Stamp `time.monotonic_ns()` just before entering a JAX profiler
+        `TraceAnnotation(name)` and again inside it, and keep
+        `(name, t_before, t_inside)` in `anchors`.  The annotation's host
+        event in a running profiler trace starts between the two stamps,
+        which gives the offset from this clock to the trace's.  Call it
+        only while a trace is being recorded, off the request path."""
+        from jax.profiler import TraceAnnotation
+
+        t_before = time.monotonic_ns()
+        with TraceAnnotation(name):
+            t_inside = time.monotonic_ns()
+        with self._lock:
+            self.anchors.append((name, t_before, t_inside))
+        return t_before, t_inside
 
     # -- reading ----------------------------------------------------------
 

@@ -484,6 +484,9 @@ class ServeScheduler:
         self._rid_trace: dict[int, tuple[str, bool]] = {}  # rid->(tid,owned)
         self._qspans: dict[int, int] = {}    # rid -> open queue_wait span
         self._wspans: dict[int, int] = {}    # rid -> open device_wait span
+        # rid -> (tid, owned, open result_wait span, outcome) of results
+        # not yet handed out
+        self._rspans: dict[int, tuple] = {}
 
         if self.overload is not None:
             self.overload.bind(self)
@@ -543,7 +546,7 @@ class ServeScheduler:
             self._expire_overdue_locked()
             for cap in list(self._queues):
                 while self._queues[cap]:
-                    self._run_bucket(cap)
+                    self._run_bucket(cap, "flush")
             while self._retire_oldest_locked():
                 pass
             if self.overload is not None:
@@ -625,7 +628,12 @@ class ServeScheduler:
             n_valid = int(np.asarray(mask, bool).sum())
             deadline = t_submit + deadline_s \
                 if deadline_s is not None else None
+        tr = self._tracer
+        # admission ends here, before the lock: the wait for it is
+        # `lock_wait` (another thread may be mapping a micro-batch)
+        t_adm = time.monotonic() if tr is not None else None
         with self._lock:
+            t_locked = time.monotonic() if tr is not None else None
             rid = self._next_rid
             self._next_rid += 1
             self._c_submitted.inc()
@@ -647,15 +655,14 @@ class ServeScheduler:
             if err is None and self.overload is not None:
                 err = self.overload.check_admission_locked(
                     cap, self._outstanding.get(cap, 0), priority)
-            tr = self._tracer
             if tr is not None:
                 tid = trace_id if trace_id is not None else \
                     f"{self.instance}:rid:{rid}"
                 tr.begin(tid, t=t_submit, rid=rid, instance=self.instance)
                 self._rid_trace[rid] = (tid, trace_id is None)
-                t_adm = time.monotonic()
                 tr.span(tid, "admission", t_start=t_submit, t_end=t_adm,
                         bucket=cap, n_points=int(n))
+                tr.span(tid, "lock_wait", t_start=t_adm, t_end=t_locked)
             if self._recorder is not None:
                 self._recorder.record("submit", rid=rid, bucket=int(cap),
                                       instance=self.instance,
@@ -664,7 +671,7 @@ class ServeScheduler:
                 self._complete_error_locked(rid, n, cap, t_submit, err)
                 return rid
             if tr is not None:
-                sid = tr.span(tid, "queue_wait", t_start=t_adm,
+                sid = tr.span(tid, "queue_wait", t_start=t_locked,
                               bucket=cap)
                 if sid is not None:
                     self._qspans[rid] = sid
@@ -682,7 +689,7 @@ class ServeScheduler:
             if len(self._queues[cap]) >= self.max_batch_for(cap):
                 if self.overload is None or self.pipeline_depth == 0 \
                         or not self._bucket_at_depth_locked(cap):
-                    self._run_bucket(cap)
+                    self._run_bucket(cap, "full")
                 # else: DEFERRED dispatch (controller mode) — the bucket
                 # is at its pipeline depth, so the batch stays queued
                 # until a slot retires (_pump_locked).  This is what
@@ -708,6 +715,7 @@ class ServeScheduler:
                     pass
             out = list(self._completed)
             self._completed.clear()
+            self._handed_out(out)
             return out
 
     def flush(self) -> int:
@@ -719,7 +727,7 @@ class ServeScheduler:
             ran = 0
             for cap in list(self._queues):
                 while self._queues[cap]:
-                    ran += self._run_bucket(cap)
+                    ran += self._run_bucket(cap, "flush")
             while self._retire_oldest_locked():
                 pass
             return ran
@@ -736,6 +744,7 @@ class ServeScheduler:
                     break
             out = list(self._completed)
             self._completed.clear()
+            self._handed_out(out)
             return out
 
     def take(self, rids) -> dict[int, ServeResult]:
@@ -757,6 +766,7 @@ class ServeScheduler:
                 else:
                     keep.append(r)
             self._completed = keep
+            self._handed_out(out.values())
             return out
 
     def serve(self, scenes) -> dict[int, ServeResult]:
@@ -815,8 +825,10 @@ class ServeScheduler:
         feats is re-staged on a hit: it is the one operand the key does
         not cover (same geometry, fresh sensor payload).
 
-        `marks` (tracing only) receives monotonic timestamps for the
-        arena-staging and cache-lookup phases plus the hit flag.
+        `marks` (tracing only) receives monotonic timestamps of the
+        phases: feats staging, the cache lookup, and on a miss the
+        coords/mask staging, each scene's mapping (`mapping`, one
+        (start, end) per request) and the stacking of the pyramids.
         """
         n_real, n_dummy = len(reqs), mb - len(reqs)
         if marks is not None:
@@ -837,6 +849,8 @@ class ServeScheduler:
         if marks is not None:
             marks["lookup_t0"] = time.monotonic()
         cached = self.assembly_cache.lookup(comp_key)
+        if marks is not None:
+            marks["lookup_t1"] = time.monotonic()
         if cached is not None:
             # the whole stacked batch is reused: every scene's mapping
             # work was skipped wholesale, so each request reports a hit
@@ -852,8 +866,17 @@ class ServeScheduler:
                 arena.mask[s, n_real:] = False
             coords_b = arena.stage(arena.coords[s])
             mask_b = arena.stage(arena.mask[s])
-            per = [self.engine._levels_padded(r.coords, r.mask, cap,
-                                              key=r.key) for r in reqs]
+            mapped = None
+            if marks is not None:
+                marks["staged_t1"] = time.monotonic()
+                mapped = marks["mapping"] = []
+            per = []
+            for r in reqs:
+                t = time.monotonic() if mapped is not None else None
+                per.append(self.engine._levels_padded(r.coords, r.mask, cap,
+                                                      key=r.key))
+                if mapped is not None:
+                    mapped.append((t, time.monotonic()))
             hits = [h for _, h in per]
             levels_b = jax.tree_util.tree_map(
                 lambda *xs: jnp.stack(xs), *[lv for lv, _ in per])
@@ -861,10 +884,12 @@ class ServeScheduler:
                 levels_b = jax.tree_util.tree_map(
                     lambda a, b: jnp.concatenate([a, b]),
                     levels_b, self._dummy_tail(reqs[0], n_dummy))
+            if marks is not None:
+                marks["stacked_t1"] = time.monotonic()
             self.assembly_cache.put(comp_key,
                                     (levels_b, coords_b, mask_b))
         if marks is not None:
-            marks["lookup_t1"] = time.monotonic()
+            marks["assembly_t1"] = time.monotonic()
             marks["cache_hit"] = cached is not None
         return hits, (levels_b, coords_b, mask_b, feats_b)
 
@@ -908,7 +933,7 @@ class ServeScheduler:
             q = self._queues[cap]
             while len(q) >= self.max_batch_for(cap) and \
                     not self._bucket_at_depth_locked(cap):
-                ran += self._run_bucket(cap)
+                ran += self._run_bucket(cap, "pump")
         return ran
 
     def _lane_order_enabled(self) -> bool:
@@ -920,13 +945,14 @@ class ServeScheduler:
         return self._has_priorities or \
             (self.overload is not None and self._has_deadlines)
 
-    def _run_bucket(self, cap: int) -> int:
+    def _run_bucket(self, cap: int, trigger: str) -> int:
         """Pop up to max_batch queued scenes and dispatch them (caller
-        holds the lock).  With priority lanes active the pop takes the
-        highest-priority scenes first, earliest deadline first within a
-        priority (EDF), FIFO within ties — the micro-batch SHAPE and
-        each scene's predictions are unchanged, only which queued
-        scenes go first."""
+        holds the lock); `trigger` says why (full / deadline / flush /
+        pump), for the dispatch span.  With priority lanes active the
+        pop takes the highest-priority scenes first, earliest deadline
+        first within a priority (EDF), FIFO within ties — the
+        micro-batch SHAPE and each scene's predictions are unchanged,
+        only which queued scenes go first."""
         q = self._queues[cap]
         mb = self.max_batch_for(cap)
         take = min(mb, len(q))
@@ -947,9 +973,9 @@ class ServeScheduler:
             reqs = [q.popleft() for _ in range(take)]
         if not reqs:
             return 0
-        return self._dispatch(reqs, cap, retries=0)
+        return self._dispatch(reqs, cap, 0, trigger)
 
-    def _dispatch(self, reqs, cap: int, retries: int) -> int:
+    def _dispatch(self, reqs, cap: int, retries: int, trigger: str) -> int:
         """Assemble + dispatch one micro-batch (caller holds the lock).
 
         Dispatch is asynchronous: the jit call returns a future-like
@@ -972,7 +998,7 @@ class ServeScheduler:
             self._c_retries.inc()
         tr = self._tracer
         t_disp = time.monotonic()
-        marks = {} if tr is not None and not self._legacy_assembly else None
+        marks = {} if tr is not None else None
         try:
             t0 = time.perf_counter()
             if self._legacy_assembly:
@@ -981,14 +1007,19 @@ class ServeScheduler:
                 hits, operands = self._assemble(reqs, cap, mb, marks)
             t1 = time.perf_counter()
             self._h_assembly.observe(t1 - t0)
+            if marks is not None:
+                marks["launch_t0"] = time.monotonic()
             preds = self._apply(*operands)
+            if marks is not None:
+                marks["launch_t1"] = time.monotonic()
         except Exception as e:
             self._on_slot_failed(
                 _InFlight(cap, list(reqs), [False] * n_real, None,
                           did, retries), e)
             return n_real
         if tr is not None:
-            self._trace_dispatch(reqs, did, cap, retries, t_disp, marks)
+            self._trace_dispatch(reqs, hits, did, cap, retries, trigger,
+                                 t_disp, marks)
         if self._recorder is not None:
             self._recorder.record(
                 "dispatch", dispatch_id=did, bucket=int(cap),
@@ -1024,14 +1055,20 @@ class ServeScheduler:
         # the depth)
         return n_real
 
-    def _trace_dispatch(self, reqs, did: int, cap: int, retries: int,
-                        t_disp: float, marks: dict | None) -> None:
+    def _trace_dispatch(self, reqs, hits, did: int, cap: int,
+                        retries: int, trigger: str, t_disp: float,
+                        marks: dict) -> None:
         """Per-request dispatch spans (caller holds the lock, tracer is
         wired in): close the queue_wait span, record the dispatch span
-        with its assembly children, open the device_wait span."""
+        with its assembly and launch children, open the device_wait
+        span.  The dispatch's stacking is recorded in every request's
+        tree (one `stack_levels` per `dispatch_id`); `mapping` only in
+        the tree of the request whose scene it mapped."""
         tr = self._tracer
-        t_launch = time.monotonic()
-        for r in reqs:
+        t_launch = marks["launch_t1"]
+        thread = threading.current_thread().name
+        mapped = marks.get("mapping")
+        for i, r in enumerate(reqs):
             tid_owned = self._rid_trace.get(r.rid)
             if tid_owned is None:
                 continue
@@ -1039,11 +1076,13 @@ class ServeScheduler:
             tr.end_span(tid, self._qspans.pop(r.rid, None), t_end=t_disp)
             dspan = tr.span(tid, "dispatch", t_start=t_disp,
                             t_end=t_launch, dispatch_id=did,
-                            bucket=cap, retries=retries)
-            if marks:
+                            bucket=cap, retries=retries,
+                            n_real=len(reqs), trigger=trigger,
+                            thread=thread)
+            if "arena_t0" in marks:
                 aspan = tr.span(tid, "assembly", parent=dspan,
                                 t_start=marks["arena_t0"],
-                                t_end=marks["lookup_t1"],
+                                t_end=marks["assembly_t1"],
                                 cache_hit=marks["cache_hit"])
                 tr.span(tid, "arena_staging", parent=aspan,
                         t_start=marks["arena_t0"],
@@ -1051,6 +1090,19 @@ class ServeScheduler:
                 tr.span(tid, "assembly_lookup", parent=aspan,
                         t_start=marks["lookup_t0"],
                         t_end=marks["lookup_t1"])
+                if mapped is not None:
+                    tr.span(tid, "arena_staging", parent=aspan,
+                            t_start=marks["lookup_t1"],
+                            t_end=marks["staged_t1"])
+                    tr.span(tid, "mapping", parent=aspan,
+                            t_start=mapped[i][0], t_end=mapped[i][1],
+                            rows=r.n_valid, bucket=cap,
+                            cache_hit=bool(hits[i]))
+                    tr.span(tid, "stack_levels", parent=aspan,
+                            t_start=mapped[-1][1],
+                            t_end=marks["stacked_t1"], dispatch_id=did)
+            tr.span(tid, "launch", parent=dspan,
+                    t_start=marks["launch_t0"], t_end=t_launch)
             sid = tr.span(tid, "device_wait", t_start=t_launch,
                           dispatch_id=did)
             if sid is not None:
@@ -1121,7 +1173,7 @@ class ServeScheduler:
         else:
             groups = (retryable,)
         for group in groups:
-            self._dispatch(group, slot.cap, slot.retries + 1)
+            self._dispatch(group, slot.cap, slot.retries + 1, "retry")
 
     def _backoff_locked(self, generation: int) -> None:
         """Jittered exponential backoff before a retry dispatch (the
@@ -1217,13 +1269,12 @@ class ServeScheduler:
             if tr is not None:
                 tid_owned = self._rid_trace.pop(r.rid, None)
                 if tid_owned is not None:
-                    tid, owned = tid_owned
+                    tid = tid_owned[0]
                     tr.end_span(tid, self._wspans.pop(r.rid, None),
                                 t_end=t_done)
                     tr.event(tid, "retire", t=t_done,
                              dispatch_id=slot.dispatch_id)
-                    if owned:
-                        tr.end(tid, t=t_done, outcome="ok")
+                    self._await_handout(r.rid, tid_owned, t_done, "ok")
         if self._recorder is not None:
             self._recorder.record(
                 "retire", dispatch_id=slot.dispatch_id,
@@ -1252,14 +1303,13 @@ class ServeScheduler:
         if self._tracer is not None:
             tid_owned = self._rid_trace.pop(rid, None)
             if tid_owned is not None:
-                tid, owned = tid_owned
+                tid = tid_owned[0]
                 self._tracer.end_span(tid, self._qspans.pop(rid, None),
                                       t_end=now)
                 self._wspans.pop(rid, None)
                 self._tracer.event(tid, "error", t=now, code=err.code,
                                    message=err.message)
-                if owned:
-                    self._tracer.end(tid, t=now, outcome=err.code)
+                self._await_handout(rid, tid_owned, now, err.code)
         if self._recorder is not None:
             self._recorder.record("error", rid=rid, code=err.code,
                                   bucket=int(bucket),
@@ -1267,6 +1317,28 @@ class ServeScheduler:
             if err.code == FLT.EXEC_FAILED:
                 self._recorder.dump("exec_failed",
                                     key=("exec_failed", self.instance, rid))
+
+    def _await_handout(self, rid: int, tid_owned: tuple, t: float,
+                       outcome: str) -> None:
+        """Open a completed request's `result_wait` span (tracer wired
+        in): it runs until poll/drain/take hands the result out."""
+        sid = self._tracer.span(tid_owned[0], "result_wait", t_start=t)
+        self._rspans[rid] = (*tid_owned, sid, outcome)
+
+    def _handed_out(self, results) -> None:
+        """Close the `result_wait` spans of the results being handed out
+        and, for traces this scheduler began, their roots."""
+        if self._tracer is not None and results:
+            t = time.monotonic()
+            for res in results:
+                h = self._rspans.pop(res.rid, None)
+                if h is None:
+                    continue
+                tid, owned, sid, outcome = h
+                self._tracer.end_span(tid, sid, t_end=t)
+                if owned:
+                    self._tracer.end(tid, t=t, outcome=outcome)
+        return results
 
     def _expire_overdue_locked(self) -> None:
         """Convert queued requests whose `deadline_s` elapsed into
@@ -1332,7 +1404,7 @@ class ServeScheduler:
                             "watchdog_deadline_flush",
                             key=("wd_flush", self.instance,
                                  int(self._c_deadline_flushes.value)))
-                self._run_bucket(cap)
+                self._run_bucket(cap, "deadline")
 
     def _watchdog_tick(self) -> None:
         """Background completion (the `watchdog_s` Ticker): fire

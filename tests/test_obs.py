@@ -10,6 +10,7 @@ and a router worker-kill produces one trace spanning original dispatch
 
 import json
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -318,14 +319,218 @@ def test_scheduler_request_span_tree(served):
         names = trace.names()
         for stage in ("request", "admission", "queue_wait", "dispatch",
                       "assembly", "arena_staging", "assembly_lookup",
-                      "device_wait", "retire"):
+                      "device_wait", "retire", "lock_wait", "mapping",
+                      "stack_levels", "launch", "result_wait"):
             assert stage in names, (rid, names)
         root = trace.spans[trace.root_id]
         assert root.attrs["outcome"] == "ok"
         (qw,) = trace.find("queue_wait")
         (dp,) = trace.find("dispatch")
         assert qw.t_end is not None and qw.t_end <= dp.t_start + 1e-9
+        # nesting: the root's children tile submit -> hand-out; assembly
+        # and launch sit under dispatch, the assembly phases under it
+        (asm,) = trace.find("assembly")
+        parent_of = {s.name: s.parent_id for s in trace.span_list()}
+        for name in ("admission", "lock_wait", "queue_wait", "dispatch",
+                     "device_wait", "retire", "result_wait"):
+            assert parent_of[name] == trace.root_id, name
+        assert parent_of["assembly"] == parent_of["launch"] == dp.span_id
+        for name in ("arena_staging", "assembly_lookup", "mapping",
+                     "stack_levels"):
+            assert parent_of[name] == asm.span_id, name
+        assert asm.attrs["cache_hit"] is False
+        (mp,) = trace.find("mapping")
+        assert mp.attrs["rows"] > 0 and mp.attrs["bucket"] in (64, 128)
+        assert dp.attrs["trigger"] == "flush" and dp.attrs["n_real"] == 1
+        (adm,) = trace.find("admission")
+        (lw,) = trace.find("lock_wait")
+        assert adm.t_end == lw.t_start and lw.t_end == qw.t_start
+        (rt,) = trace.find("retire")
+        (rw,) = trace.find("result_wait")
+        assert rw.t_start == rt.t_end and root.t_end == rw.t_end
     sched.close()
+
+
+def _uncovered(trace) -> float:
+    """Share of the root not covered by the union of its children."""
+    root = trace.spans[trace.root_id]
+    kids = sorted((s.t_start, s.t_end) for s in trace.span_list()
+                  if s.parent_id == root.span_id)
+    covered, end = 0.0, root.t_start
+    for a, b in kids:
+        a, b = max(a, end), min(b, root.t_end)
+        if b > a:
+            covered += b - a
+            end = b
+    return 1.0 - covered / (root.t_end - root.t_start)
+
+
+def test_dispatch_trigger_deadline_vs_full(served):
+    """A partial micro-batch flushed by max_wait_s on the watchdog and a
+    full bucket dispatched in submit say so on their dispatch spans; on
+    both paths the root's children cover it from submit to the poll that
+    hands the result out."""
+    _, engine = served
+    obs = Observability.enabled()
+    sched = ServeScheduler(engine, max_batch=2, max_wait_s=0.05, obs=obs,
+                           instance="tg")
+    lone = sched.submit(*_scene(20, 40))
+    time.sleep(0.5)                     # nobody polls: the watchdog flushes
+    pair = [sched.submit(*_scene(21, 40)), sched.submit(*_scene(22, 40))]
+    want, got = {lone, *pair}, {}
+    deadline = time.monotonic() + 60
+    while want - set(got) and time.monotonic() < deadline:
+        got.update((r.rid, r) for r in sched.poll())
+        time.sleep(0.002)
+    assert set(got) == want and all(r.ok for r in got.values())
+    (d,) = obs.tracer.get(f"tg:rid:{lone}").find("dispatch")
+    assert d.attrs["trigger"] == "deadline"
+    assert d.attrs["thread"] == "serve-watchdog"
+    assert d.attrs["n_real"] == 1
+    for rid in pair:
+        (d,) = obs.tracer.get(f"tg:rid:{rid}").find("dispatch")
+        assert d.attrs["trigger"] == "full" and d.attrs["n_real"] == 2
+        assert d.attrs["thread"] == threading.current_thread().name
+    for rid in want:
+        trace = obs.tracer.get(f"tg:rid:{rid}")
+        assert trace.closed
+        assert _uncovered(trace) <= 0.01, (rid, trace.names())
+    sched.close()
+
+
+def test_lock_wait_behind_another_threads_mapping(served, monkeypatch):
+    """A submit made while another thread's dispatch maps a micro-batch
+    under the scheduler lock records the wait as `lock_wait`, after its
+    admission ended."""
+    _, engine = served
+    obs = Observability.enabled()
+    sched = ServeScheduler(engine, max_batch=2, obs=obs, instance="lw")
+    mapping = threading.Event()
+    real = engine._build
+
+    def slow_build(*args):
+        mapping.set()
+        time.sleep(0.3)
+        return real(*args)
+
+    monkeypatch.setattr(engine, "_build", slow_build)
+
+    def fill_bucket():                  # the second submit maps both
+        sched.submit(*_scene(30, 40))
+        sched.submit(*_scene(31, 40))
+
+    t = threading.Thread(target=fill_bucket, name="producer")
+    t.start()
+    assert mapping.wait(60)
+    rid = sched.submit(*_scene(32, 40))
+    t.join()
+    sched.flush()
+    out = sched.drain()
+    assert len(out) == 3 and all(r.ok for r in out)
+    trace = obs.tracer.get(f"lw:rid:{rid}")
+    (adm,) = trace.find("admission")
+    (lw,) = trace.find("lock_wait")
+    assert adm.t_end <= lw.t_start
+    assert lw.t_end - lw.t_start > 0.2
+    other = obs.tracer.get(f"lw:rid:{rid - 1}")
+    (d,) = other.find("dispatch")
+    assert d.attrs["thread"] == "producer" and d.attrs["trigger"] == "full"
+    maps = [s for s in other.find("mapping")]
+    assert len(maps) == 1 and maps[0].attrs["cache_hit"] is False
+    assert maps[0].t_end - maps[0].t_start >= 0.3
+    sched.close()
+
+
+def test_tracer_off_records_nothing(served, monkeypatch):
+    """With no tracer the scheduler keeps no trace state, hands `_assemble`
+    no marks, reads the clock only where the untraced path always did,
+    and serves the same predictions as a traced scheduler."""
+    from repro.serve import scheduler as SCH
+
+    _, engine = served
+    scenes = [_scene(40, 40), _scene(41, 90)]
+    marks_seen = []
+    real_assemble = ServeScheduler._assemble
+
+    def assemble(self, reqs, cap, mb, marks=None):
+        marks_seen.append(marks)
+        return real_assemble(self, reqs, cap, mb, marks)
+
+    monkeypatch.setattr(ServeScheduler, "_assemble", assemble)
+    calls = [0]
+    real_time = SCH.time
+
+    class _Clock:
+        @staticmethod
+        def monotonic():
+            calls[0] += 1
+            return real_time.monotonic()
+
+        def __getattr__(self, name):
+            return getattr(real_time, name)
+
+    plain = ServeScheduler(engine, max_batch=2, instance="off")
+    traced = ServeScheduler(engine, max_batch=2, instance="on",
+                            obs=Observability.enabled())
+    monkeypatch.setattr(SCH, "time", _Clock())
+    ref = plain.serve(scenes)
+    # one read per submit, per dispatch and per retire: two scenes in
+    # two buckets
+    assert calls[0] == 6
+    calls[0] = 0
+    got = traced.serve(scenes)
+    assert calls[0] > 6
+    monkeypatch.undo()
+    assert marks_seen[:2] == [None, None]
+    assert all(m is not None for m in marks_seen[2:])
+    for rid in ref:
+        np.testing.assert_array_equal(ref[rid].preds, got[rid].preds)
+    assert plain.obs.tracer is None
+    for state in (plain._rid_trace, plain._qspans, plain._wspans,
+                  plain._rspans):
+        assert state == {}
+    plain.close()
+    traced.close()
+
+
+def test_anchor_puts_spans_on_the_profiler_clock(tmp_path):
+    """A span recorded around a `TraceAnnotation("probe")` lands on the
+    probe's host event within 1 ms once mapped through the first clock
+    anchor's offset."""
+    import glob
+
+    from jax.profiler import ProfileData, TraceAnnotation
+
+    tr = SpanTracer()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        tr.anchor()
+        tr.begin("p", t=time.monotonic())
+        t0 = time.monotonic()
+        with TraceAnnotation("probe"):
+            time.sleep(0.02)
+        tr.span("p", "probe", t_start=t0, t_end=time.monotonic())
+        time.sleep(0.05)
+        tr.anchor()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(f"{tmp_path}/**/*.xplane.pb", recursive=True)
+    host = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name in ("obs.clock", "probe"):
+                    host.setdefault(e.name, []).append(
+                        (e.start_ns, e.start_ns + e.duration_ns))
+    assert len(host["obs.clock"]) == 2 and len(host["probe"]) == 1
+    assert [a[0] for a in tr.anchors] == ["obs.clock"] * 2
+    _, before, inside = tr.anchors[0]
+    assert before <= inside
+    offset = min(host["obs.clock"])[0] - (before + inside) / 2
+    (span,) = tr.get("p").find("probe")
+    (p0, p1) = host["probe"][0]
+    assert abs(span.t_start * 1e9 + offset - p0) < 1e6
+    assert abs(span.t_end * 1e9 + offset - p1) < 1e6
 
 
 def test_error_latency_separate_histogram(served):
