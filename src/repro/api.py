@@ -123,7 +123,7 @@ class MappingCache(_LruCache):
     features, so repeated geometry — a parked scanner, multi-sweep
     aggregation, re-scored frames — is served from cache: one cheap
     blake2b over the coordinate bytes decides whether the ranking sort +
-    binary searches run at all (~microseconds vs ~tens of ms).
+    key lookups run at all (~microseconds vs ~tens of ms).
 
     Values are whatever the builder returns (typically a jit-built level
     pyramid of concrete arrays).  Hit/miss/eviction counters are exposed

@@ -32,11 +32,15 @@ Two ranking engines coexist:
     offset (the original, any spatial dimensionality).
   * v2 ("packed", default for D=3): bit-pack each coordinate into one 62-bit
     key (repro.core.packed), sort every cloud ONCE into a `SortedCloud`
-    cache, and realise each kernel offset as a vectorised binary search of
-    the shifted output keys against the sorted input keys — K merge-sorts
-    collapse to 1 sort + K searches, and the sorted cloud is reused by every
+    cache, and realise each kernel offset as a lookup of the shifted
+    output keys in the sorted input keys (`match_table`) — K merge-sorts
+    collapse to 1 sort + K lookups, and the sorted cloud is reused by every
     mapping op at the same stride (all submanifold convs of a network level
     share one sort; `downsample_sorted` dedups the already-packed keys).
+    The lookup is chosen by backend when the program is lowered: on the
+    TPU a bitonic merge of the (already sorted) shifted output keys with
+    the input keys, gather- and loop-free; elsewhere a vectorised binary
+    search.
 """
 
 from __future__ import annotations
@@ -85,14 +89,14 @@ class KernelMaps(NamedTuple):
     (input index, output index) pairs, padded with -1 / valid=False.
 
     `inv` is the inverse table inv[k, j] = input index feeding output j under
-    offset k (-1 if none).  The v2 engine emits it for free — its binary
-    search is indexed by output row, so the hit positions ARE the inverse
-    table — letting the Pallas FoD kernel skip the scatter pass that v1
-    needed (kernels/spconv/ops.invert_maps).  None on the v1 path.
+    offset k (-1 if none).  The v2 engine emits it for free — its key
+    lookup is indexed by output row, so the hits ARE the inverse table —
+    letting the Pallas FoD kernel skip the scatter pass that v1 needed
+    (kernels/spconv/ops.invert_maps).  None on the v1 path.
 
     `inv_t` is the same table for the *swapped* maps: inv_t[k, i] = output
     index feeding input i when the maps are used transposed (decoder
-    up-convolution).  The v2 engine computes it with one extra binary search
+    up-convolution).  The v2 engine computes it with one extra key lookup
     per offset (mapping.match_table) so `swap()` hands the Pallas kernel a
     ready inverse table — the decoder never falls back to a scatter pass.
     """
@@ -300,7 +304,7 @@ def kernel_map(in_pc: PointCloud, out_pc: PointCloud, kernel_size: int,
 
 
 # ---------------------------------------------------------------------------
-# v2 packed-key engine: one sort per cloud, binary search per offset
+# v2 packed-key engine: one sort per cloud, one key lookup per offset
 # ---------------------------------------------------------------------------
 
 class SortedCloud(NamedTuple):
@@ -324,8 +328,8 @@ class SortedCloud(NamedTuple):
 def sort_cloud(pc: PointCloud) -> SortedCloud:
     """Rank a cloud once: pack coords to 62-bit keys and sort them.
 
-    The ONLY `lax.sort` the v2 engine runs for a given cloud — every
-    kernel-offset lookup afterwards is a binary search.
+    The only ranking sort the v2 engine runs for a given cloud — every
+    kernel-offset lookup afterwards is `match_table` against it.
     """
     if pc.ndim_spatial != 3:
         raise ValueError("packed-key engine requires 3 spatial dims, got "
@@ -379,56 +383,70 @@ def downsample_sorted(sc: SortedCloud, factor: int = 2) -> SortedCloud:
     return SortedCloud(pc, c_hi, c_lo, jnp.arange(n, dtype=jnp.int32))
 
 
-def match_table(sc: SortedCloud, query_pc: PointCloud,
+# Backends on which `match_table` lowers to the gather-free merge: a TPU
+# gathers element by element, so the binary search's ~log2(n) gather rounds
+# dominate the mapping there, while on a CPU they are cheap and the merge
+# network's log2(n + m) full passes are not.
+MERGE_PLATFORMS = ("tpu",)
+
+
+def lookup_kind(platform: str) -> str:
+    """"merge" or "binary": the lookup `match_table` lowers to on
+    `platform` (a JAX backend name such as `jax.default_backend()`)."""
+    return "merge" if platform in MERGE_PLATFORMS else "binary"
+
+
+def match_table(sc: SortedCloud, query: SortedCloud,
                 offsets) -> jnp.ndarray:
-    """table[k, j] = row of sc.pc at coords (query_pc.coords[j] + offsets[k]),
-    or -1 when that site is absent.
+    """table[k, j] = row of sc.pc at coords (query.pc.coords[j] +
+    offsets[k]), or -1 when that site is absent.
 
-    The primitive behind every v2 inverse table: pack the shifted query
-    coords and binary-search them against the cloud's sorted keys.  Pure
-    ranking — no scatter, no hash.  `offsets` is (K, D) static (numpy or
-    jnp); the batch column is never shifted.
+    The one key lookup of the v2 engine, behind every map and inverse
+    table: pure ranking — no scatter, no hash.  `offsets` is (K, 3)
+    static (numpy or jnp); the batch column is never shifted.  The
+    backend picks the implementation when the program is lowered
+    (`lookup_kind`): on the TPU the bitonic merge of the shifted sorted
+    query keys with the sorted table (`PK.lookup_merge`), elsewhere a
+    binary search of the shifted query coords (`PK.lookup_binary`).  Both
+    give the same table bit for bit on clouds inside the key budget (the
+    only clouds the v2 engine accepts).
     """
-    n = sc.pc.capacity
-    q_spatial = query_pc.coords[None, :, 1:] + jnp.asarray(offsets)[:, None, :]
-    q_batch = jnp.broadcast_to(query_pc.coords[None, :, :1],
-                               (q_spatial.shape[0], query_pc.capacity, 1))
-    q_hi, q_lo = PK.pack_coords(jnp.concatenate([q_batch, q_spatial], -1),
-                                query_pc.mask[None, :])
-    pos = PK.searchsorted_pair(sc.sorted_hi, sc.sorted_lo, q_hi, q_lo)
-    posc = jnp.clip(pos, 0, n - 1)
-    hit = ((sc.sorted_hi[posc] == q_hi) & (sc.sorted_lo[posc] == q_lo)
-           & ~PK.is_sentinel_key(q_hi))
-    return jnp.where(hit, sc.perm[posc], jnp.int32(-1))
+    def binary(t_hi, t_lo, t_rows, coords, mask, *_):
+        q_spatial = coords[:, 1:][None] + jnp.asarray(offsets)[:, None, :]
+        q_batch = jnp.broadcast_to(coords[:, :1][None],
+                                   (q_spatial.shape[0], coords.shape[0], 1))
+        q_hi, q_lo = PK.pack_coords(
+            jnp.concatenate([q_batch, q_spatial], -1), mask[None, :])
+        return PK.lookup_binary(t_hi, t_lo, t_rows, q_hi, q_lo)
+
+    def merge(t_hi, t_lo, t_rows, _, __, q_hi, q_lo, q_rows):
+        return PK.lookup_merge(t_hi, t_lo, t_rows, q_hi, q_lo, q_rows,
+                               offsets)
+
+    return lax.platform_dependent(
+        sc.sorted_hi, sc.sorted_lo, sc.perm, query.pc.coords,
+        query.pc.mask, query.sorted_hi, query.sorted_lo, query.perm,
+        default=binary, **{p: merge for p in MERGE_PLATFORMS})
 
 
-def kernel_map_v2(in_sc: SortedCloud, out_pc: PointCloud, kernel_size: int,
+def kernel_map_v2(in_sc: SortedCloud, out_sc: SortedCloud, kernel_size: int,
                   cap: int | None = None) -> KernelMaps:
     """Packed-key kernel mapping: for output q and offset delta, the paired
-    input is p = q + delta — found by binary-searching key(q + delta) in the
-    input cloud's sorted keys.  One vectorised search per offset replaces
-    v1's full merge-sort per offset, and because the search is indexed by
-    output row the hit table IS the inverse table the Pallas FoD kernel
-    wants (KernelMaps.inv) — no scatter pass.
+    input is p = q + delta — found by looking key(q + delta) up in the
+    input cloud's sorted keys (`match_table`: a binary search, or on the
+    TPU a merge of the already-sorted output keys).  One vectorised
+    lookup of all offsets replaces v1's full merge-sort per offset, and
+    because the lookup is indexed by output row the hit table IS the
+    inverse table the Pallas FoD kernel wants (KernelMaps.inv) — no
+    scatter pass.
     """
     offs = kernel_offsets(kernel_size, 3, in_sc.pc.stride)
-    m = out_pc.capacity
+    m = out_sc.pc.capacity
     n = in_sc.pc.capacity
     cap = cap if cap is not None else min(n, m)
 
-    # queries: (K, m, 4) shifted output coords (batch col untouched)
-    q_spatial = out_pc.coords[None, :, 1:] + jnp.asarray(offs)[:, None, :]
-    q_batch = jnp.broadcast_to(out_pc.coords[None, :, :1],
-                               (offs.shape[0], m, 1))
-    q_hi, q_lo = PK.pack_coords(jnp.concatenate([q_batch, q_spatial], -1),
-                                out_pc.mask[None, :])
-
-    pos = PK.searchsorted_pair(in_sc.sorted_hi, in_sc.sorted_lo, q_hi, q_lo)
-    posc = jnp.clip(pos, 0, n - 1)
-    hit = ((in_sc.sorted_hi[posc] == q_hi) & (in_sc.sorted_lo[posc] == q_lo)
-           & ~PK.is_sentinel_key(q_hi))
-
-    in_idx = jnp.where(hit, in_sc.perm[posc], jnp.int32(-1))
+    in_idx = match_table(in_sc, out_sc, offs)
+    hit = in_idx >= 0
     out_idx = jnp.where(
         hit, jnp.broadcast_to(jnp.arange(m, dtype=jnp.int32), hit.shape),
         jnp.int32(-1))
@@ -469,20 +487,20 @@ def build_conv_maps_cached(sc: SortedCloud, kernel_size: int, stride: int,
     cache) to skip recomputing it.
 
     Strided maps additionally carry the swapped inverse table `inv_t`
-    (searching the coarse cloud from the fine coords), so the decoder's
+    (looking the fine coords up in the coarse cloud), so the decoder's
     transposed convs run the scatter-free Pallas path via `maps.swap()`.
     The table is only exact while `cap` drops no matches — the default cap
     covers every match, a user-supplied smaller one may not.
     """
     if out_sc is None:
         out_sc = sc if stride == 1 else downsample_sorted(sc, stride)
-    maps = kernel_map_v2(sc, out_sc.pc, kernel_size, cap=cap)
+    maps = kernel_map_v2(sc, out_sc, kernel_size, cap=cap)
     resolved_cap = cap if cap is not None else min(sc.pc.capacity,
                                                    out_sc.pc.capacity)
     if stride > 1 and resolved_cap >= out_sc.pc.capacity:
         # swapped orientation: fine output i under swapped offset -delta is
         # fed by the coarse row at (fine_coords[i] - delta)
-        inv_t = match_table(out_sc, sc.pc, -maps.offsets)
+        inv_t = match_table(out_sc, sc, -maps.offsets)
         maps = maps._replace(inv_t=inv_t)
     return maps, out_sc
 

@@ -221,3 +221,123 @@ def searchsorted_pair(s_hi: jnp.ndarray, s_lo: jnp.ndarray,
     n_steps = max(1, int(np.ceil(np.log2(n + 1))) + 1)
     lo_i, _ = lax.fori_loop(0, n_steps, step, (lo_i, hi_i))
     return lo_i
+
+
+# -- exact-match lookup: table row of each shifted query key, or -1 ---------
+#
+# Two implementations of one lookup, bit-identical in result on every cloud
+# inside the key budget; which one a backend takes is decided in
+# repro.core.mapping.match_table.
+
+def lookup_binary(s_hi: jnp.ndarray, s_lo: jnp.ndarray, rows: jnp.ndarray,
+                  q_hi: jnp.ndarray, q_lo: jnp.ndarray) -> jnp.ndarray:
+    """Row of each packed query key (any shape) in a sorted key table, or
+    -1: binary search, then three gathers at the hit position.  `rows`
+    maps sorted position -> table row; sentinel queries never match.
+    Cheap where a gather is cheap (CPU); on the TPU each of its ~log2(n)
+    rounds is an element-by-element gather over every query."""
+    n = s_hi.shape[0]
+    pos = jnp.clip(searchsorted_pair(s_hi, s_lo, q_hi, q_lo), 0, n - 1)
+    hit = (s_hi[pos] == q_hi) & (s_lo[pos] == q_lo) & ~is_sentinel_key(q_hi)
+    return jnp.where(hit, rows[pos], jnp.int32(-1))
+
+
+def shift_keys(hi: jnp.ndarray, lo: jnp.ndarray, offsets):
+    """Packed keys (m,) of coords c -> keys (K, m) of c + offsets[k] in
+    the key domain, plus (K, m) `ok`: the shifted coords lie inside the
+    key budget and the source key was not the sentinel.
+
+    The shift is one two-word integer add of the constant
+    dx*2^32 + dy*2^16 + dz, so it preserves key order along m: a sorted
+    key array stays sorted under every offset.  Where `ok` holds the
+    result is exactly `pack_coords(c + offsets[k])`; elsewhere it is an
+    order-keeping placeholder (sentinel sources stay the sentinel) that
+    may alias a valid key, so callers must mask it with `ok`.
+    """
+    offsets = jnp.asarray(offsets, jnp.int32)
+    dx, dy, dz = (offsets[:, i:i + 1] for i in range(3))
+    low = dy * (1 << SPATIAL_BITS) + dz
+    s_lo = lo[None] + low.astype(jnp.uint32)
+    s_hi = (hi[None] + dx - (low < 0).astype(jnp.int32)
+            + (s_lo < lo[None]).astype(jnp.int32))
+    src = ~is_sentinel_key(hi)[None]
+    ok = src
+    for field, d in (((hi & np.int32(0xFFFF)), dx),
+                     ((lo >> SPATIAL_BITS).astype(jnp.int32), dy),
+                     ((lo & _LO16).astype(jnp.int32), dz)):
+        f = field[None] + d
+        ok = ok & (f >= 0) & (f <= 0xFFFF)
+    return (jnp.where(src, s_hi, KEY_HI_SENTINEL),
+            jnp.where(src, s_lo, KEY_LO_SENTINEL), ok)
+
+
+def _after(a, b):
+    """a sorts after b: (hi signed, lo unsigned, aux descending)."""
+    (ah, al, ax), (bh, bl, bx) = a, b
+    return (ah > bh) | ((ah == bh) & ((al > bl) | ((al == bl) & (ax < bx))))
+
+
+def _partner(x: jnp.ndarray, s: int, upper: jnp.ndarray) -> jnp.ndarray:
+    """Compare-exchange partner at distance s along the last axis."""
+    return jnp.where(upper, jnp.roll(x, s, axis=-1), jnp.roll(x, -s, axis=-1))
+
+
+def lookup_merge(t_hi: jnp.ndarray, t_lo: jnp.ndarray, t_rows: jnp.ndarray,
+                 q_hi: jnp.ndarray, q_lo: jnp.ndarray, q_rows: jnp.ndarray,
+                 offsets) -> jnp.ndarray:
+    """table[k, j] = row of the sorted key table at key(query row j +
+    offsets[k]), or -1: the paper's merge sorter + DetectIntersection,
+    with neither a gather nor a loop.
+
+    Both clouds are sorted key arrays (`t_rows`/`q_rows` map sorted
+    position -> row).  `shift_keys` keeps each offset's queries sorted, so
+    each of the K rows is one bitonic merge: the table ascending, padding
+    that sorts last, then the queries descending, merged by log2(L)
+    compare-exchange stages of rolls and selects over (K, L).  Ties put
+    the table first (the third word: table row >= 0, query -1, masked
+    query -2, padding -3), so a query matches iff the element just before
+    it is a table row with its key: queries of one offset are distinct
+    and table keys are distinct.  Replaying the recorded swaps in reverse
+    routes each answer back to its query, and one single-key sort on
+    `q_rows` returns the rows to query order.
+    """
+    n, m = t_hi.shape[0], q_hi.shape[0]
+    s_hi, s_lo, ok = shift_keys(q_hi, q_lo, offsets)
+    k = s_hi.shape[0]
+    width = 1 << int(np.ceil(np.log2(n + m)))
+
+    def row(table, pad, queries):
+        return jnp.concatenate(
+            [jnp.broadcast_to(table, (k, n)),
+             jnp.full((k, width - n - m), pad, queries.dtype),
+             queries[:, ::-1]], axis=1)
+
+    # masked table rows share the sentinel key: one word for all of them
+    # keeps the table's run ascending (they never match)
+    t_aux = jnp.where(is_sentinel_key(t_hi), 0, t_rows.astype(jnp.int32))
+    words = (row(t_hi, KEY_HI_SENTINEL, s_hi),
+             row(t_lo, KEY_LO_SENTINEL, s_lo),
+             row(t_aux, -3, jnp.where(ok, jnp.int32(-1), jnp.int32(-2))))
+    pos = lax.broadcasted_iota(jnp.int32, (k, width), 1)
+    stages = []
+    s = width // 2
+    while s >= 1:
+        upper = (pos & s) != 0
+        other = tuple(_partner(w, s, upper) for w in words)
+        first = tuple(jnp.where(upper, o, w) for w, o in zip(words, other))
+        second = tuple(jnp.where(upper, w, o) for w, o in zip(words, other))
+        swap = _after(first, second)
+        words = tuple(jnp.where(swap, o, w) for w, o in zip(words, other))
+        stages.append((s, upper, swap))
+        s //= 2
+
+    hi, lo, aux = words
+    p_hi, p_lo, p_aux = (jnp.roll(w, 1, axis=1) for w in words)
+    hit = (aux == -1) & (p_aux >= 0) & (p_hi == hi) & (p_lo == lo)
+    found = jnp.where(hit, p_aux, jnp.int32(-1))
+    for s, upper, swap in reversed(stages):
+        found = jnp.where(swap, _partner(found, s, upper), found)
+    found = found[:, width - m:][:, ::-1]         # sorted query order
+    _, found = lax.sort((jnp.broadcast_to(q_rows, (k, m)), found),
+                        dimension=1, num_keys=1)
+    return found

@@ -17,7 +17,7 @@ hand.  This module is that shape for the PointAcc reproduction:
 
 All mapping state is computed lazily and memoized: the first conv at a
 stride level ranks the cloud (one `lax.sort`), every later conv at that
-level is binary searches against the cached `SortedCloud` — the paper's
+level is key lookups against the cached `SortedCloud` — the paper's
 one-sort-per-level invariant, now enforced by the context instead of by
 careful call-site plumbing.
 
@@ -137,7 +137,8 @@ class MapContext:
                   factor: int = 1) -> tuple[M.KernelMaps, M.PointCloud]:
         """Maps + output cloud for a (possibly strided) conv, memoized.
 
-        v2: binary searches against the level's SortedCloud; strided maps
+        v2: key lookups (`M.match_table`: a merge on the TPU, a binary
+        search elsewhere) against the level's SortedCloud; strided maps
         additionally carry the swapped inverse table (`inv_t`) so the
         matching transposed conv stays scatter-free.  v1: per-offset
         lexicographic merge-sort (any spatial dimensionality).

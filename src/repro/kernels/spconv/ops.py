@@ -29,7 +29,7 @@ def invert_maps(maps: KernelMaps, out_cap: int) -> jnp.ndarray:
     """(K, cap) map lists -> (K, out_cap) inverse table inv[k, j] = i.
 
     The v2 packed-key engine emits the inverse table directly from its
-    binary-search hit positions (KernelMaps.inv) — that path is a no-op
+    key-lookup hits (KernelMaps.inv) — that path is a no-op
     here, and since PR 2 it covers swapped maps too: strided v2 maps carry
     the transposed table (KernelMaps.inv_t), which `swap()` promotes to
     `inv`, so decoder transposed convs stay scatter-free.  Only v1 maps

@@ -180,8 +180,9 @@ def build_unet_maps(pc: M.PointCloud, n_stages: int,
     With the packed-key engine (default) each level's cloud is ranked
     exactly ONCE: the level's SortedCloud serves its 27 submanifold
     offsets AND the 8 down-conv offsets, and the downsample hands the next
-    level its cloud already sorted — one `lax.sort` per stride level for
-    the entire network, every conv afterwards is binary search.
+    level its cloud already sorted — one ranking sort per stride level
+    for the entire network, every conv afterwards is key lookups
+    (`M.match_table`).
     """
     ctx = MapContext(engine=engine)
     ctx.register_cloud(pc.stride, pc)

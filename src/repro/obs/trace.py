@@ -21,7 +21,7 @@ children tile it from `submit` to the hand-out of the result:
     │  │  ├─ arena_staging     3.90 → 4.00  (feats)
     │  │  ├─ assembly_lookup   4.00 → 4.01  (the AssemblyCache lookup)
     │  │  ├─ arena_staging     4.01 → 4.05  (coords, mask; on a miss)
-    │  │  ├─ mapping           4.05 → 4.60  rows bucket cache_hit
+    │  │  ├─ mapping           4.05 → 4.60  rows bucket cache_hit search
     │  │  └─ stack_levels      4.60 → 4.90  dispatch_id=7
     │  └─ launch      4.90 → 5.10 ms        host call of the jitted apply
     ├─ device_wait    5.10 → 38.7 ms
@@ -32,7 +32,10 @@ children tile it from `submit` to the hand-out of the result:
 `submit`), `deadline` (`max_wait_s`), `flush` (flush/close), `pump`
 (a deferred batch under an overload controller) or `retry`.  `mapping`
 is recorded in the tree of the request whose scene it mapped,
-`stack_levels` in every request of the micro-batch.
+`stack_levels` in every request of the micro-batch.  A `mapping` that
+missed the cache names the key lookup its build lowered to in `search`
+(`merge` on the TPU, `binary` elsewhere; `repro.core.mapping.
+lookup_kind`); the v1 engine's builds carry none.
 
 Failure paths appear as spans too: `dispatch_failed`, `error`,
 `failover` (attrs: dead worker + reason), `replay` (attrs: surviving

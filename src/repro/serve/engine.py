@@ -22,7 +22,7 @@ entry point instead of one per distinct point count.
 
 The Mapping Unit output depends only on coordinates, so repeated geometry
 (parked scanner, multi-sweep aggregation, re-scored frames) skips the
-ranking sort + binary searches entirely on a cache hit.
+ranking sort + key lookups entirely on a cache hit.
 
 The token-LM serving engine (`ServeEngine` and friends) lives in
 `repro.serve.lm`.

@@ -375,6 +375,10 @@ class ServeScheduler:
         # the packed-key budget is only a constraint for the v2 engine
         self._check_key_budget = \
             getattr(engine.session.config, "engine", None) != "v1"
+        # the key lookup a cache-miss build lowers to on this backend
+        # (`search` of the `mapping` span); the v1 engine has none
+        self._search = M.lookup_kind(jax.default_backend()) \
+            if self._check_key_budget else None
         self._legacy_assembly = assembly_cache_entries == 0
         self.assembly_cache = None if self._legacy_assembly else \
             AssemblyCache(assembly_cache_entries)
@@ -1094,10 +1098,12 @@ class ServeScheduler:
                     tr.span(tid, "arena_staging", parent=aspan,
                             t_start=marks["lookup_t1"],
                             t_end=marks["staged_t1"])
+                    search = {} if hits[i] or self._search is None \
+                        else {"search": self._search}
                     tr.span(tid, "mapping", parent=aspan,
                             t_start=mapped[i][0], t_end=mapped[i][1],
                             rows=r.n_valid, bucket=cap,
-                            cache_hit=bool(hits[i]))
+                            cache_hit=bool(hits[i]), **search)
                     tr.span(tid, "stack_levels", parent=aspan,
                             t_start=mapped[-1][1],
                             t_end=marks["stacked_t1"], dispatch_id=did)

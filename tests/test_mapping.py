@@ -2,6 +2,7 @@
 
 import numpy as np
 import pytest
+import jax
 import jax.numpy as jnp
 from tests.hypothesis_compat import given, settings, st
 
@@ -207,6 +208,42 @@ def test_v2_inverse_table_matches_v1_scatter():
                 np.asarray(sw.inv))
         else:
             assert m2.swap().inv is None
+
+
+@pytest.mark.parametrize("kernel_size,stride", [(3, 1), (2, 2)])
+@pytest.mark.parametrize("cap", [1, 7, 128, 4096])
+def test_v2_merge_lookup_matches_binary(monkeypatch, cap, kernel_size,
+                                        stride):
+    """The TPU's merge lookup of `match_table`, forced onto this
+    backend, builds the same maps and inverse tables bit for bit as the
+    binary search: in_idx, out_idx, valid, inv and (strided) inv_t, on a
+    shuffled cloud (perm not the identity) with masked rows."""
+    rng = np.random.default_rng(cap + stride)
+    grid = max(4, int(np.ceil((2 * cap) ** (1 / 3))))
+    n_valid = max(1, (3 * cap) // 4)
+    coords, mask = random_cloud(rng, n_valid, cap, grid=grid)
+    pc = M.make_point_cloud(jnp.asarray(coords), jnp.asarray(mask))
+
+    def build():        # traced afresh: the lowering is chosen at trace
+        return jax.jit(lambda c, m: M.build_conv_maps(
+            M.PointCloud(c, m, 1), kernel_size, stride,
+            engine="v2")[0])(pc.coords, pc.mask)
+
+    assert M.lookup_kind(jax.default_backend()) == "binary"
+    binary = build()
+    monkeypatch.setattr(M, "MERGE_PLATFORMS", (jax.default_backend(),))
+    assert M.lookup_kind(jax.default_backend()) == "merge"
+    merge = build()
+    fields = ["in_idx", "out_idx", "valid", "inv"]
+    if stride > 1:
+        fields.append("inv_t")
+    for f in fields:
+        np.testing.assert_array_equal(np.asarray(getattr(merge, f)),
+                                      np.asarray(getattr(binary, f)),
+                                      err_msg=f)
+    n_maps = int(np.sum(np.asarray(merge.valid)))
+    # each valid row pairs with itself (k=3), or with its one cell (k=2)
+    assert n_maps >= n_valid if stride == 1 else n_maps == n_valid
 
 
 def test_downsample_sorted_matches_downsample():

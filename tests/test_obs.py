@@ -16,6 +16,7 @@ import numpy as np
 import pytest
 import jax
 
+from repro.core import mapping as M
 from repro.data.synthetic import lidar_scene
 from repro.obs import (FlightRecorder, Histogram, MetricsRegistry,
                        Observability, SpanTracer, TraceSchemaError,
@@ -341,6 +342,10 @@ def test_scheduler_request_span_tree(served):
         assert asm.attrs["cache_hit"] is False
         (mp,) = trace.find("mapping")
         assert mp.attrs["rows"] > 0 and mp.attrs["bucket"] in (64, 128)
+        # a cache-miss build names the key lookup this backend lowers to
+        assert mp.attrs.get("search") == (
+            None if mp.attrs["cache_hit"]
+            else M.lookup_kind(jax.default_backend()))
         assert dp.attrs["trigger"] == "flush" and dp.attrs["n_real"] == 1
         (adm,) = trace.find("admission")
         (lw,) = trace.find("lock_wait")
@@ -437,6 +442,7 @@ def test_lock_wait_behind_another_threads_mapping(served, monkeypatch):
     assert d.attrs["thread"] == "producer" and d.attrs["trigger"] == "full"
     maps = [s for s in other.find("mapping")]
     assert len(maps) == 1 and maps[0].attrs["cache_hit"] is False
+    assert maps[0].attrs["search"] == M.lookup_kind(jax.default_backend())
     assert maps[0].t_end - maps[0].t_start >= 0.3
     sched.close()
 

@@ -13,6 +13,7 @@ repro.core.packed:
 
 import numpy as np
 import pytest
+import jax
 import jax.numpy as jnp
 
 from repro.core import mapping as M
@@ -181,3 +182,75 @@ def test_searchsorted_pair_matches_numpy(n, nq):
     qkey = q_hi.astype(np.uint64) * 2**32 + q_lo.astype(np.uint64)
     expect = np.searchsorted(key, qkey, side="left")
     np.testing.assert_array_equal(got, expect)
+
+
+# ---------------------------------------------------------------------------
+# exact-match lookup: the merge and binary lowerings against numpy
+# ---------------------------------------------------------------------------
+
+LOOKUP_CASES = ["dense", "masked", "empty", "sentinel_queries",
+                "budget_edges"]
+
+
+def _lookup_clouds(case, cap, seed):
+    """(table, query) sorted clouds of `cap` rows for one case, both in
+    shuffled row order (perm is not the identity).  The query cloud is
+    the table's coords under its own mask, except: "empty" masks every
+    table row, "sentinel_queries" every query row.  Under all 27 unit
+    offsets neighbours give duplicate query keys across offsets."""
+    rng = np.random.default_rng(seed)
+    g = int(np.ceil(cap ** (1 / 3))) + 2
+    lin = rng.choice(2 * g ** 3, size=cap, replace=False)
+    b, rest = np.divmod(lin, g ** 3)
+    xyz = np.stack(np.unravel_index(rest, (g, g, g)), axis=1)
+    if case == "budget_edges":
+        # half of each axis at COORD_MIN.., half at ..COORD_MAX: their
+        # +-1 shifts leave the key budget and saturate to the sentinel
+        xyz = np.where(xyz < g // 2, PK.COORD_MIN + xyz, PK.COORD_MAX - xyz)
+    coords = np.concatenate([b[:, None], xyz], axis=1).astype(np.int32)
+    mask = rng.random(cap) < 0.6 if case == "masked" else np.ones(cap, bool)
+
+    def cloud(m):
+        c = np.where(m[:, None], coords, M.SENTINEL)
+        return M.sort_cloud(M.PointCloud(jnp.asarray(c), jnp.asarray(m), 1))
+
+    table = cloud(np.zeros(cap, bool) if case == "empty" else mask)
+    query = cloud(np.zeros(cap, bool) if case == "sentinel_queries"
+                  else np.ones(cap, bool) if case == "empty" else mask)
+    return table, query
+
+
+@pytest.mark.parametrize("case", LOOKUP_CASES)
+@pytest.mark.parametrize("cap", [1, 7, 128, 4096])
+def test_lookup_merge_matches_binary_and_numpy(cap, case):
+    """Both lookups behind `match_table` give the numpy answer bit for
+    bit: np.searchsorted over the composed uint64 keys of the shifted
+    query coords, hit where the key at the position equals the
+    (non-sentinel) query.  The merge takes the query cloud's sorted keys,
+    the binary search its packed shifted coords."""
+    table, query = _lookup_clouds(case, cap, seed=cap)
+    offs = M.kernel_offsets(3, 3, 1)
+    q = np.asarray(query.pc.coords)[None].repeat(len(offs), 0)
+    q[..., 1:] += offs[:, None, :]
+    q_hi, q_lo = PK.pack_coords(jnp.asarray(q), query.pc.mask[None])
+    t = (table.sorted_hi, table.sorted_lo, table.perm)
+    binary = np.asarray(jax.jit(PK.lookup_binary)(*t, q_hi, q_lo))
+    merge = np.asarray(jax.jit(
+        lambda *a: PK.lookup_merge(*a, offs))(
+            *t, query.sorted_hi, query.sorted_lo, query.perm))
+
+    key = PK.compose_key64(table.sorted_hi, table.sorted_lo)
+    qkey = PK.compose_key64(q_hi, q_lo)
+    pos = np.minimum(np.searchsorted(key, qkey, side="left"), cap - 1)
+    hit = (key[pos] == qkey) & (qkey != PK.KEY64_SENTINEL)
+    expect = np.where(hit, np.asarray(table.perm)[pos], -1)
+
+    assert merge.shape == binary.shape == (27, cap)
+    np.testing.assert_array_equal(merge, expect)
+    np.testing.assert_array_equal(binary, expect)
+    if case in ("empty", "sentinel_queries"):
+        assert not hit.any()
+    else:
+        # every valid row finds itself under the zero offset
+        mask = np.asarray(query.pc.mask)
+        assert (merge[13][mask] == np.arange(cap)[mask]).all()

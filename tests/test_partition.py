@@ -169,7 +169,7 @@ def _subm_neighbor_sets(coords, k=3):
     mask = np.ones(coords.shape[0], bool)
     pc = M.make_point_cloud(jnp.asarray(coords), jnp.asarray(mask))
     sc = M.sort_cloud(pc)
-    inv = np.asarray(M.kernel_map_v2(sc, pc, k).inv)
+    inv = np.asarray(M.kernel_map_v2(sc, sc, k).inv)
     cn = np.asarray(pc.coords)
     return {tuple(cn[j]): frozenset(tuple(cn[inv[o, j]])
                                     for o in range(inv.shape[0])
@@ -184,7 +184,7 @@ def _down_member_sets(coords):
     pc = M.make_point_cloud(jnp.asarray(coords), jnp.asarray(mask))
     sc0 = M.sort_cloud(pc)
     sc1 = M.downsample_sorted(sc0)
-    inv = np.asarray(M.kernel_map_v2(sc0, sc1.pc, 2).inv)
+    inv = np.asarray(M.kernel_map_v2(sc0, sc1, 2).inv)
     c0 = np.asarray(pc.coords)
     c1, m1 = np.asarray(sc1.pc.coords), np.asarray(sc1.pc.mask)
     return {tuple(c1[j]): frozenset(tuple(c0[inv[o, j]])

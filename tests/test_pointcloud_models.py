@@ -77,24 +77,13 @@ def test_minkunet(flow):
     assert np.all(np.asarray(out)[~mask] == 0)
 
 
-def _jaxprs_in(value):
-    if hasattr(value, "jaxpr"):                    # ClosedJaxpr
-        return [value.jaxpr]
-    if hasattr(value, "eqns"):                     # Jaxpr
-        return [value]
-    if isinstance(value, (list, tuple)):
-        return [j for v in value for j in _jaxprs_in(v)]
-    return []
-
-
-def _count_sort_eqns(jaxpr):
-    total = 0
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "sort":
-            total += 1
-        for v in eqn.params.values():
-            total += sum(_count_sort_eqns(j) for j in _jaxprs_in(v))
-    return total
+def _count_lowered_sorts(fn, *args) -> int:
+    """`lax.sort`s in fn lowered for the CPU, whose key lookup is the
+    binary search: the cloud-ranking sorts alone.  (A jaxpr holds every
+    branch of `match_table`'s backend choice, the TPU merge's query-order
+    sorts included; a lowering holds the one that runs.)"""
+    lowered = jax.jit(fn).trace(*args).lower(lowering_platforms=("cpu",))
+    return lowered.as_text().count("stablehlo.sort")
 
 
 def test_unet_maps_one_sort_per_level():
@@ -110,8 +99,8 @@ def test_unet_maps_one_sort_per_level():
         return [(l["pc"].coords, l["subm"].in_idx,
                  l.get("down", l["subm"]).in_idx) for l in levels]
 
-    jaxpr = jax.make_jaxpr(build)(jnp.asarray(coords), jnp.asarray(mask))
-    n_sorts = _count_sort_eqns(jaxpr.jaxpr)
+    args = (jnp.asarray(coords), jnp.asarray(mask))
+    n_sorts = _count_lowered_sorts(build, *args)
     assert n_sorts == n_stages + 1, n_sorts
 
     def build_v1(c, m):
@@ -120,8 +109,48 @@ def test_unet_maps_one_sort_per_level():
         return [(l["pc"].coords, l["subm"].in_idx,
                  l.get("down", l["subm"]).in_idx) for l in levels]
 
-    jaxpr1 = jax.make_jaxpr(build_v1)(jnp.asarray(coords), jnp.asarray(mask))
-    assert _count_sort_eqns(jaxpr1.jaxpr) > 3 * n_sorts
+    assert _count_lowered_sorts(build_v1, *args) > 3 * n_sorts
+
+
+def _build_levels(c, m):
+    return MU.build_unet_maps(M.PointCloud(c, m, 1), 2)
+
+
+def test_unet_maps_tpu_lowering_has_no_loop_or_gather():
+    """Structure guard: lowered for the TPU (lowering only, no chip), the
+    whole mapping pass at a 65,536-row bucket holds no while loop and no
+    gather — every key lookup is the merge network.  The CPU lowering
+    keeps the binary search's loops."""
+    n = 65536
+    shapes = (jax.ShapeDtypeStruct((n, 4), jnp.int32),
+              jax.ShapeDtypeStruct((n,), jnp.bool_))
+
+    def lowered(platform):
+        return jax.jit(_build_levels).trace(*shapes).lower(
+            lowering_platforms=(platform,)).as_text()
+
+    tpu = lowered("tpu")
+    assert "stablehlo.while" not in tpu
+    assert "gather" not in tpu
+    assert "stablehlo.while" in lowered("cpu")
+
+
+def test_unet_maps_merge_matches_binary(monkeypatch):
+    """The default lowering's pyramid (binary search) equals the one the
+    TPU's merge builds, forced onto this backend, leaf for leaf and bit
+    for bit: maps, inverse tables and clouds at every level."""
+    rng = np.random.default_rng(21)
+    coords, mask = random_cloud(rng, 300, 512, grid=12)
+    args = (jnp.asarray(coords), jnp.asarray(mask))
+    binary = jax.jit(_build_levels)(*args)
+    monkeypatch.setattr(M, "MERGE_PLATFORMS", (jax.default_backend(),))
+    merge = jax.jit(_build_levels)(*args)
+    flat_b, tree_b = jax.tree_util.tree_flatten_with_path(binary)
+    flat_m, tree_m = jax.tree_util.tree_flatten_with_path(merge)
+    assert tree_b == tree_m
+    for (path, b), (_, m) in zip(flat_b, flat_m):
+        np.testing.assert_array_equal(np.asarray(m), np.asarray(b),
+                                      err_msg=jax.tree_util.keystr(path))
 
 
 def test_minkunet_engines_agree():
